@@ -50,6 +50,9 @@ def main() -> None:
                          "(default: the repo root; '' disables)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
+
     from benchmarks import (bench_async_vs_sync, bench_chaos,
                             bench_collaboration, bench_comm_timing,
                             bench_convergence, bench_cop_surface,

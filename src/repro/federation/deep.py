@@ -333,6 +333,22 @@ def _flat_shardings_for(mesh, theta_L, bank):
     return flat_shardings(mesh, n, theta_L.size)
 
 
+def _gather_row(bank: jax.Array, idx, sh) -> jax.Array:
+    """Row `idx` of a dense (N, P) bank.
+
+    With the owner rows sharded over the mesh, a dynamic index makes
+    GSPMD all-gather the whole bank every round (and on TPU compiles in
+    time proportional to P). A masked sum over the row axis instead
+    reduces each device's rows locally and all-reduces one (P,) row; it
+    holds one nonzero term per element, so the row comes back exactly
+    (up to the sign of a zero)."""
+    if sh is None or sh.bank.spec[0] is None:
+        return jax.lax.dynamic_index_in_dim(bank, idx, 0, keepdims=False)
+    hit = (jnp.arange(bank.shape[0]) == idx)[:, None]
+    return jnp.sum(jnp.where(hit, bank, jnp.zeros((), bank.dtype)), axis=0,
+                   dtype=bank.dtype)
+
+
 def _constrain(x, sharding):
     """with_sharding_constraint that understands ParamFlat and None."""
     if sharding is None:
@@ -595,7 +611,7 @@ def _round_math(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array]):
 
 
 def _flat_clipped_grad_acc(loss_fn, spec: FlatSpec, pcfg: PrivatizerConfig,
-                           tb: jax.Array, batch):
+                           tb: jax.Array, batch, mesh=None):
     """Sum of per-group clipped (P,) gradients at theta_bar + group gain.
 
     The gradient is the ordinary tree gradient at `spec.unpack(tb)` packed
@@ -615,7 +631,7 @@ def _flat_clipped_grad_acc(loss_fn, spec: FlatSpec, pcfg: PrivatizerConfig,
 
     def sqnorm(g):
         return fused_sqnorm_tree(g, block_rows=pcfg.kernel_block_rows,
-                                 interpret=interp)
+                                 interpret=interp, mesh=mesh)
 
     if pcfg.granularity == "example":
         B = jax.tree_util.tree_leaves(batch)[0].shape[0]
@@ -703,8 +719,7 @@ def _round_math_flat(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array],
         if isinstance(hot, QuantBank):
             theta_i = _decode_bank_row(hot, ridx, pcfg)            # (P,)
         else:
-            theta_i = jax.lax.dynamic_index_in_dim(hot, ridx, 0,
-                                                   keepdims=False)  # (P,)
+            theta_i = _gather_row(hot, ridx, sh)                    # (P,)
         if sh is not None:
             # the gathered row keeps the bank's P-axis layout (== theta's),
             # so theta_bar and the whole round stay local in P
@@ -722,7 +737,7 @@ def _round_math_flat(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array],
             tb = 0.5 * (theta_L.buf + theta_eff)                   # (6)
             ns = scales[owner_idx]
             acc, gain, pm = _flat_clipped_grad_acc(loss_fn, spec, pcfg,
-                                                   tb, batch)
+                                                   tb, batch, mesh)
             if tree_on:
                 # tree mechanism: the round key feeds ONLY the tree op
                 # (the fresh node draw); the response adds the node
@@ -748,7 +763,8 @@ def _round_math_flat(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array],
                     sigma=cfg.sigma, lr_own=lr_own, lr_l=lr_L, n_owners=N,
                     theta_max=cfg.theta_max,
                     block_rows=pcfg.kernel_block_rows,
-                    interpret=resolve_interpret(pcfg.kernel_interpret))
+                    interpret=resolve_interpret(pcfg.kernel_interpret),
+                    mesh=mesh)
                 new_row = tree_row
             metrics = {"clip_frac": pm["clip_frac"],
                        "max_grad_norm": pm["max_grad_norm"],

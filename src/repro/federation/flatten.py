@@ -44,6 +44,11 @@ def _check_dtype(dt: np.dtype) -> np.dtype:
     return dt
 
 
+def _concat_f32(leaves) -> jax.Array:
+    return jnp.concatenate([jnp.ravel(leaf).astype(jnp.float32)
+                            for leaf in leaves])
+
+
 @dataclasses.dataclass(frozen=True)
 class FlatSpec:
     """Static shape/dtype/layout metadata of a packed pytree.
@@ -85,33 +90,33 @@ class FlatSpec:
         trace it becomes a `with_sharding_constraint` (so packing inside
         a jitted round keeps the buffer sharded instead of gathering it),
         eagerly it reshards the concrete array. Values are identical
-        either way.
-
-        Implementation note: the buffer is assembled with a chain of
-        static dynamic_update_slice ops, NOT one jnp.concatenate. The
-        placement is bit-identical, but XLA:CPU's SPMD partitioner
-        (jaxlib 0.4.3x) miscompiles concatenate of slices of a PARTIALLY
-        sharded operand — e.g. a (P,) buffer sharded over 'model' on a
-        (data, model) mesh comes back scaled by the unused axis size —
-        while the update-slice chain lowers to local writes under every
-        sharding (verified by the sharded-engine parity tests)."""
+        either way."""
         leaves = self.validate(tree)
-        buf = jnp.zeros((self.size,), jnp.float32)
-        for off, leaf in zip(self.offsets, leaves):
-            buf = jax.lax.dynamic_update_slice(
-                buf, jnp.ravel(leaf).astype(jnp.float32), (off,))
+        buf = _concat_f32(leaves)
         if sharding is not None:
             buf = jax.lax.with_sharding_constraint(buf, sharding)
         return buf
 
-    def unpack(self, buf: jax.Array) -> Any:
-        """(P,) buffer -> pytree with the original shapes/dtypes."""
+    def _leaf_slices(self, buf: jax.Array) -> list:
+        """The (n,) slice of every leaf, behind an optimization barrier.
+
+        Without the barrier XLA rewrites slice-then-reshape of the whole
+        buffer into one reshape of the buffer to (P/k, k), k the smallest
+        minor dimension among the leaves, followed by row slices. On TPU
+        a minor dimension of k < 128 pads every tile to 128 lanes: for
+        xlstm-125m (k = 4) that is 12 GB of the 16 GB chip. The barrier
+        costs one copy of the buffer."""
         if buf.shape != (self.size,):
             raise ValueError(f"buffer shape {buf.shape} != ({self.size},)")
-        leaves = []
-        for off, shape, dt in zip(self.offsets, self.shapes, self.dtypes):
-            n = int(np.prod(shape, dtype=np.int64)) if shape else 1
-            leaves.append(buf[off:off + n].reshape(shape).astype(dt))
+        return jax.lax.optimization_barrier([
+            buf[off:off + (int(np.prod(shape, dtype=np.int64))
+                           if shape else 1)]
+            for off, shape in zip(self.offsets, self.shapes)])
+
+    def unpack(self, buf: jax.Array) -> Any:
+        """(P,) buffer -> pytree with the original shapes/dtypes."""
+        leaves = [part.reshape(shape).astype(dt) for part, shape, dt in
+                  zip(self._leaf_slices(buf), self.shapes, self.dtypes)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     # Shape-only variants for SIDE-CHANNEL buffers that ride the model's
@@ -127,24 +132,17 @@ class FlatSpec:
         if treedef != self.treedef:
             raise ValueError(f"tree structure mismatch: got {treedef}, "
                              f"spec has {self.treedef}")
-        buf = jnp.zeros((self.size,), jnp.float32)
-        for off, shape, leaf in zip(self.offsets, self.shapes, leaves):
+        for shape, leaf in zip(self.shapes, leaves):
             if tuple(leaf.shape) != shape:
                 raise ValueError(f"leaf shape mismatch: got {leaf.shape}, "
                                  f"spec has {shape}")
-            buf = jax.lax.dynamic_update_slice(
-                buf, jnp.ravel(leaf).astype(jnp.float32), (off,))
-        return buf
+        return _concat_f32(leaves)
 
     def unpack_f32(self, buf: jax.Array) -> Any:
         """(P,) f32 buffer -> pytree with the spec's shapes, dtype KEPT
         f32 (no per-leaf downcast)."""
-        if buf.shape != (self.size,):
-            raise ValueError(f"buffer shape {buf.shape} != ({self.size},)")
-        leaves = []
-        for off, shape in zip(self.offsets, self.shapes):
-            n = int(np.prod(shape, dtype=np.int64)) if shape else 1
-            leaves.append(buf[off:off + n].reshape(shape))
+        leaves = [part.reshape(shape) for part, shape in
+                  zip(self._leaf_slices(buf), self.shapes)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
@@ -448,9 +446,10 @@ def init_flat_bank(flat: ParamFlat, n_owners: int, dtype=None,
             residual = jax.lax.with_sharding_constraint(residual,
                                                         residual_sharding)
         return QuantBank(codes, scales, residual, codec)
-    bank = jnp.broadcast_to(flat.buf[None], (n_owners, flat.size))
-    if dtype is not None:
-        bank = bank.astype(dtype)
+    # narrow before broadcasting: an eager f32 (N, P) intermediate would
+    # cost twice the bf16 bank it becomes
+    row = flat.buf if dtype is None else flat.buf.astype(dtype)
+    bank = jnp.broadcast_to(row[None], (n_owners, flat.size))
     if sharding is not None:
         bank = jax.lax.with_sharding_constraint(bank, sharding)
     return bank

@@ -36,12 +36,14 @@ from jax.experimental import pallas as pl
 from repro.kernels.bank_codec.ref import (CODE_DTYPES, decode_fp8_ref,
                                           decode_int8_ref, encode_fp8_ref,
                                           encode_int8_ref, row_scales_ref)
+from repro.kernels.dp_clip_noise.kernel import PARTIAL_TILE
 
 LANES = 1024
 
 
 def _absmax_kernel(x_ref, o_ref):
-    o_ref[0, 0] = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
+    o_ref[...] = jnp.full(PARTIAL_TILE,
+                          jnp.max(jnp.abs(x_ref[...].astype(jnp.float32))))
 
 
 def _encode_kernel(x_ref, u_ref, s_ref, q_ref, e_ref, *, fmt):
@@ -61,13 +63,14 @@ def absmax_2d(x: jax.Array, *, block_rows: int = 256,
     """Blockwise partial absmax; caller takes the max. x: (R, LANES) f32."""
     R, C = x.shape
     assert C == LANES and R % block_rows == 0, (x.shape, block_rows)
-    grid = (R // block_rows,)
+    nb = R // block_rows
     partial = pl.pallas_call(
         _absmax_kernel,
-        grid=grid,
+        grid=(nb,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R // block_rows, 1), jnp.float32),
+        out_specs=pl.BlockSpec(PARTIAL_TILE, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (nb * PARTIAL_TILE[0], PARTIAL_TILE[1]), jnp.float32),
         interpret=interpret,
     )(x)
     return jnp.max(partial)

@@ -25,13 +25,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dp_clip_noise.ref import u01_from_bits
+
 INT8_QMAX = 127.0
 FP8_QMAX = 448.0          # largest finite float8_e4m3fn
 _TINY = 1e-30             # scale floor: an all-zero row decodes to zeros
-
-
-def u01_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
 
 
 def det_bits(shape) -> jnp.ndarray:
@@ -91,25 +89,26 @@ def decode_int8_ref(codes: jnp.ndarray, scale) -> jnp.ndarray:
 # measured), while frexp/ldexp/floor vectorize. The magnitude patterns of
 # finite e4m3fn values are monotone, so bits+1 is the next grid point —
 # and since the encoder clips to FP8_QMAX (0x7E), the NaN pattern 0x7F is
-# never produced.
+# never produced. Patterns are held as int32 between the uint8 codes at
+# either end: the TPU compiler has no shifts on 8-bit vectors and no
+# float32 -> uint8 conversion.
 
-def _fp8_decode_mag(b8: jnp.ndarray) -> jnp.ndarray:
-    """|value| of e4m3fn magnitude bit patterns (sign bit must be 0).
+def _fp8_decode_mag(b: jnp.ndarray) -> jnp.ndarray:
+    """|value| of int32 e4m3fn magnitude bit patterns (sign bit 0).
 
     Pure integer construction (frexp/ldexp lower to scalar libm calls on
     XLA:CPU): normal = (8+m) * 2^(e-10), with the power of two built
     directly as an f32 bit pattern ((e-10)+127 biased exponent)."""
-    e = (b8 >> 3).astype(jnp.int32)
-    m = (b8 & jnp.uint8(7)).astype(jnp.int32)
-    two_pow = jax.lax.bitcast_convert_type(
-        ((e + 117) << 23).astype(jnp.int32), jnp.float32)
+    e = b >> 3
+    m = b & 7
+    two_pow = jax.lax.bitcast_convert_type((e + 117) << 23, jnp.float32)
     normal = (8 + m).astype(jnp.float32) * two_pow
-    subnormal = m * jnp.float32(1.0 / (1 << 9))
+    subnormal = m.astype(jnp.float32) * jnp.float32(1.0 / (1 << 9))
     return jnp.where(e > 0, normal, subnormal)
 
 
 def _fp8_floor_bits(a: jnp.ndarray) -> jnp.ndarray:
-    """Largest e4m3fn magnitude pattern <= a (a in [0, FP8_QMAX]).
+    """Largest e4m3fn magnitude pattern <= a (a in [0, FP8_QMAX]), int32.
 
     Truncates the f32 bit pattern directly: for normal e4m3 values the
     f32 fields map as e = E - 120, m = top 3 mantissa bits (truncation
@@ -117,8 +116,8 @@ def _fp8_floor_bits(a: jnp.ndarray) -> jnp.ndarray:
     ab = jax.lax.bitcast_convert_type(a, jnp.int32)
     e = ((ab >> 23) & 0xFF) - 120            # e4m3 exponent field
     m = (ab >> 20) & 0x7                     # top 3 mantissa bits
-    normal = ((e << 3) | m).astype(jnp.uint8)
-    subnormal = jnp.floor(a * (1 << 9)).astype(jnp.uint8)
+    normal = (e << 3) | m
+    subnormal = jnp.floor(a * (1 << 9)).astype(jnp.int32)
     return jnp.where(a < 1.0 / (1 << 6), subnormal, normal)
 
 
@@ -129,13 +128,13 @@ def fp8_sr(y: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
     (even select and scatter), so the codec keeps fp8 codes as raw bytes
     end to end and only materializes f32 values (`fp8_to_f32`)."""
     a = jnp.abs(y)
-    lo8 = _fp8_floor_bits(a)
-    hi8 = lo8 + jnp.uint8(1)
-    lo = _fp8_decode_mag(lo8)
-    hi = _fp8_decode_mag(hi8)
+    lo_b = _fp8_floor_bits(a)
+    hi_b = lo_b + 1
+    lo = _fp8_decode_mag(lo_b)
+    hi = _fp8_decode_mag(hi_b)
     p = jnp.where(a > lo, (a - lo) / (hi - lo), 0.0)
-    out8 = jnp.where(u < p, hi8, lo8)
-    return jnp.where(y < 0, out8 | jnp.uint8(0x80), out8)
+    out = jnp.where(u < p, hi_b, lo_b)
+    return jnp.where(y < 0, out | 0x80, out).astype(jnp.uint8)
 
 
 def fp8_to_f32(codes: jnp.ndarray) -> jnp.ndarray:
@@ -143,9 +142,9 @@ def fp8_to_f32(codes: jnp.ndarray) -> jnp.ndarray:
     f8-typed array is accepted and viewed as bytes), bypassing astype."""
     if codes.dtype != jnp.uint8:
         codes = jax.lax.bitcast_convert_type(codes, jnp.uint8)
-    b = codes
-    mag = _fp8_decode_mag(b & jnp.uint8(0x7F))
-    return jnp.where((b >> 7) > 0, -mag, mag)
+    b = codes.astype(jnp.int32)
+    mag = _fp8_decode_mag(b & 0x7F)
+    return jnp.where(b > 0x7F, -mag, mag)
 
 
 def encode_fp8_ref(x: jnp.ndarray, bits: jnp.ndarray, scale) -> tuple:

@@ -31,21 +31,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dp_clip_noise.ref import laplace_from_bits
+
 LANES = 1024
-
-
-def _laplace_from_bits(bits):
-    # uniform in (0,1): use top 24 bits
-    u01 = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    v = u01 - 0.5
-    # inverse CDF of Laplace(0,1): -sign(v) * log(1 - 2|v|)
-    return -jnp.sign(v) * jnp.log1p(-2.0 * jnp.abs(jnp.clip(v, -0.4999999,
-                                                            0.4999999)))
+# A partial reduction (sqnorm here, absmax in bank_codec) fills one (8, 128)
+# tile with its block's scalar: the smallest output block the TPU lowering
+# accepts.
+PARTIAL_TILE = (8, 128)
 
 
 def _scale_noise_kernel(g_ref, u_ref, cs_ref, ns_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
-    lap = _laplace_from_bits(u_ref[...])
+    lap = laplace_from_bits(u_ref[...])
     cs = cs_ref[0, 0]
     ns = ns_ref[0, 0]
     o_ref[...] = (g * cs + ns * lap).astype(o_ref.dtype)
@@ -70,7 +67,7 @@ def _dp_round_kernel(tb_ref, acc_ref, u_ref, gn_ref, ns_ref, w_ref,
     """
     tb = tb_ref[...].astype(jnp.float32)
     acc = acc_ref[...].astype(jnp.float32)
-    lap = _laplace_from_bits(u_ref[...])
+    lap = laplace_from_bits(u_ref[...])
     q = acc * gn_ref[0, 0] + ns_ref[0, 0] * lap
     g_reg = sigma * tb
     oi_ref[...] = jnp.clip(tb - lr_own * (g_reg * inv_2n + w_ref[0, 0] * q),
@@ -81,7 +78,7 @@ def _dp_round_kernel(tb_ref, acc_ref, u_ref, gn_ref, ns_ref, w_ref,
 
 def _sqnorm_kernel(g_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
-    o_ref[0, 0] = jnp.sum(g * g)
+    o_ref[...] = jnp.full(PARTIAL_TILE, jnp.sum(g * g))
 
 
 def scale_noise_2d(g: jax.Array, bits: jax.Array, clip_scale: jax.Array,
@@ -142,13 +139,14 @@ def sqnorm_2d(g: jax.Array, *, block_rows: int = 256,
     """Blockwise partial squared norms; caller sums. g: (R, LANES) fp32."""
     R, C = g.shape
     assert C == LANES and R % block_rows == 0
-    grid = (R // block_rows,)
+    nb = R // block_rows
     partial = pl.pallas_call(
         _sqnorm_kernel,
-        grid=grid,
+        grid=(nb,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R // block_rows, 1), jnp.float32),
+        out_specs=pl.BlockSpec(PARTIAL_TILE, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * PARTIAL_TILE[0], PARTIAL_TILE[1]),
+                                       jnp.float32),
         interpret=interpret,
     )(g)
-    return jnp.sum(partial)
+    return jnp.sum(partial[::PARTIAL_TILE[0], 0])

@@ -63,10 +63,25 @@ def dp_privatize_tree(grads: Any, key, xi: float, noise_scale: float, *,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def on_every_device(fn, mesh):
+    """`fn` run whole on every device of `mesh`.
+
+    GSPMD cannot partition a Mosaic (compiled Pallas) kernel, so on a
+    multi-device mesh the kernel runs inside a shard_map over replicated
+    operands: each device computes what the one-device program would.
+    No mesh, or a mesh of one device, returns `fn` as it is."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec
+    return jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
+
+
 def dp_round_flat(tb: jax.Array, acc: jax.Array, key, gain, noise_scale,
                   w, *, sigma: float, lr_own: float, lr_l: float,
                   n_owners: int, theta_max: float, block_rows: int = 256,
-                  interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+                  interpret: bool = False,
+                  mesh=None) -> Tuple[jax.Array, jax.Array]:
     """Whole inertia round on a (P,) flat buffer -> (new_L, new_i).
 
     Fuses group-mean (`gain`), the Laplace add (eq. 4), the eq. (5)/(7)
@@ -77,6 +92,8 @@ def dp_round_flat(tb: jax.Array, acc: jax.Array, key, gain, noise_scale,
     converted in-kernel by inverse CDF — a DIFFERENT lawful draw than
     jax.random.laplace, so this backend is statistically (not bitwise)
     equivalent to the jnp path: the same contract as fused_scale_noise_tree.
+    `mesh` (the engine's device mesh, if any) runs the kernel through
+    `on_every_device`.
     """
     if interpret == "oracle":
         from repro.kernels.dp_clip_noise.ref import dp_round_ref
@@ -90,10 +107,12 @@ def dp_round_flat(tb: jax.Array, acc: jax.Array, key, gain, noise_scale,
     gn = jnp.asarray(gain, jnp.float32).reshape(1, 1)
     ns = jnp.asarray(noise_scale, jnp.float32).reshape(1, 1)
     wv = jnp.asarray(w, jnp.float32).reshape(1, 1)
-    new_l, new_i = dp_round_2d(p_tb, p_acc, bits, gn, ns, wv, sigma=sigma,
-                               lr_own=lr_own, lr_l=lr_l, n_owners=n_owners,
-                               theta_max=theta_max, block_rows=block_rows,
-                               interpret=interpret)
+    kernel = functools.partial(
+        dp_round_2d, sigma=sigma, lr_own=lr_own, lr_l=lr_l,
+        n_owners=n_owners, theta_max=theta_max, block_rows=block_rows,
+        interpret=interpret)
+    new_l, new_i = on_every_device(kernel, mesh)(p_tb, p_acc, bits, gn, ns,
+                                                 wv)
     return new_l.reshape(-1)[:n], new_i.reshape(-1)[:n]
 
 
@@ -104,15 +123,16 @@ def dp_round_flat(tb: jax.Array, acc: jax.Array, key, gain, noise_scale,
 # the mechanism's scales array.
 
 def fused_sqnorm_tree(tree: Any, *, block_rows: int = 256,
-                      interpret=False) -> jax.Array:
-    """Global squared L2 norm of a pytree via the blockwise Pallas pass."""
+                      interpret=False, mesh=None) -> jax.Array:
+    """Global squared L2 norm of a pytree via the blockwise Pallas pass
+    (run through `on_every_device` on `mesh`)."""
     leaves = jax.tree_util.tree_leaves(tree)
     if interpret == "oracle":
         from repro.kernels.dp_clip_noise.ref import sqnorm_ref
         return sum(sqnorm_ref(leaf) for leaf in leaves)
-    return sum(sqnorm_2d(_pack(leaf, block_rows)[0], block_rows=block_rows,
-                         interpret=interpret)
-               for leaf in leaves)
+    kernel = on_every_device(functools.partial(
+        sqnorm_2d, block_rows=block_rows, interpret=interpret), mesh)
+    return sum(kernel(_pack(leaf, block_rows)[0]) for leaf in leaves)
 
 
 def fused_scale_noise_tree(tree: Any, key, gain, noise_scale, *,

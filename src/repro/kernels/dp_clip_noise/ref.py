@@ -4,9 +4,16 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
+def u01_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
+    """uint32 bits -> uniform [0, 1) from the top 24 bits. The shifted
+    value fits an int32 exactly, and the TPU compiler has no direct
+    uint32 -> float32 conversion, so the cast goes through int32."""
+    return ((bits >> 8).astype(jnp.int32).astype(jnp.float32)
+            * (1.0 / (1 << 24)))
+
+
 def laplace_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    u01 = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    v = u01 - 0.5
+    v = u01_from_bits(bits) - 0.5
     return -jnp.sign(v) * jnp.log1p(
         -2.0 * jnp.abs(jnp.clip(v, -0.4999999, 0.4999999)))
 

@@ -24,20 +24,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dp_clip_noise.ref import laplace_from_bits
+
 LANES = 1024
-
-
-def _laplace_from_bits(bits):
-    u01 = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    v = u01 - 0.5
-    return -jnp.sign(v) * jnp.log1p(
-        -2.0 * jnp.abs(jnp.clip(v, -0.4999999, 0.4999999)))
 
 
 def _tree_delta_kernel(nodes_ref, u_ref, cnt_ref, ns_ref, delta_ref,
                        out_ref, *, depth):
     t1 = cnt_ref[0, 0] + 1
-    zeta = ns_ref[0, 0] * _laplace_from_bits(u_ref[...])
+    zeta = ns_ref[0, 0] * laplace_from_bits(u_ref[...])
     acc = zeta
     for lvl in range(depth):
         rem = jax.lax.rem(t1, jnp.int32(1 << (lvl + 1)))

@@ -4,11 +4,22 @@ Target: TPU v5e pods — 256 chips/pod arranged (data=16, model=16);
 multi-pod doubles with a leading 'pod' axis (2, 16, 16) = 512 chips.
 A FUNCTION (not a module-level constant) so importing never touches jax
 device state.
+
+Every mesh is built with `AxisType.Auto` axes: the engine places its
+buffers with GSPMD-style `with_sharding_constraint`, while `jax.make_mesh`
+defaults to explicit axes, under which those constraints raise
+`ShardingTypeError`.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,9 +28,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     need = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) == need:
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     if len(devs) > need:   # e.g. single-pod mesh on a 512-device dry-run host
-        return jax.make_mesh(shape, axes, devices=devs[:need])
+        return _auto_mesh(shape, axes, devs[:need])
     raise RuntimeError(
         f"need {need} devices for mesh {shape}, have {len(devs)} — "
         "run under launch/dryrun.py (it forces 512 host devices)")
@@ -28,7 +39,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many devices the host actually has."""
     devs = jax.devices()[: data * model]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devs)
+    return _auto_mesh((data, model), ("data", "model"), devs)
 
 
 def make_host_mesh(*, model: int = 1):

@@ -79,7 +79,7 @@ def _make_fed(loss_fn, priv, horizon=3, mesh=None, **kw):
 # ------------------------------ rules ---------------------------------------
 def test_flat_axes_prefers_owner_rows_on_data():
     from jax.sharding import AbstractMesh
-    mesh = AbstractMesh((("data", 4), ("model", 2)))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     n_ax, p_ax = flat_axes(mesh, n_owners=8, p=64)
     assert n_ax == ("data",) and p_ax == ("model",)
     assert flat_bank_spec(mesh, 8, 64) == jax.sharding.PartitionSpec(
@@ -88,7 +88,7 @@ def test_flat_axes_prefers_owner_rows_on_data():
 
 def test_flat_axes_folds_data_into_p_when_owners_dont_divide():
     from jax.sharding import AbstractMesh
-    mesh = AbstractMesh((("data", 4), ("model", 2)))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     n_ax, p_ax = flat_axes(mesh, n_owners=3, p=64)   # 3 % 4 != 0
     assert n_ax is None and p_ax == ("model", "data")
     # and degrades to replication when nothing divides
@@ -98,7 +98,7 @@ def test_flat_axes_folds_data_into_p_when_owners_dont_divide():
 
 def test_flat_axes_multi_pod_data_axes():
     from jax.sharding import AbstractMesh
-    mesh = AbstractMesh((("pod", 2), ("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     n_ax, p_ax = flat_axes(mesh, n_owners=8, p=64)
     assert n_ax == ("pod", "data") and p_ax == ("model",)
 
@@ -186,7 +186,7 @@ def test_bank_rows_actually_shard_across_devices(toy):
     fed = _make_fed(loss_fn, priv, mesh=mesh)
     state = fed.init_state(params)
     spec = state.bank.sharding.spec
-    assert spec[0] == ("data",)          # owner rows over the data axis
+    assert spec[0] in ("data", ("data",))  # owner rows over the data axis
     shard_rows = {s.data.shape[0] for s in state.bank.addressable_shards}
     assert shard_rows == {N_OWNERS // mesh.shape["data"]}
     # theta replicates over data, shards P over model when divisible
@@ -410,6 +410,52 @@ def test_owner_parallel_with_fused_kernel_and_mesh(toy):
     led = fed.reconcile(s)
     assert all(led[i]["responses"] == 2 and led[i]["refused"] == 4
                for i in range(4))
+
+
+def test_kernel_backend_on_host_mesh_matches_one_device(toy):
+    # The Pallas kernels (here through the interpreter, as the compiled
+    # Mosaic kernel would be) cannot be partitioned by GSPMD: on a
+    # multi-device mesh they run per device over replicated operands,
+    # and the result equals the one-device mesh's.
+    params, batches, loss_fn, _ = toy
+    priv = PrivatizerConfig(xi=1e-3, granularity="microbatch",
+                            n_microbatches=2, fused_kernel=True,
+                            kernel_block_rows=8, kernel_interpret=True)
+    from repro.launch.mesh import make_debug_mesh
+    seq = jnp.asarray(np.arange(K) % 4, jnp.int32)
+    runs = []
+    for mesh in (make_host_mesh(), make_debug_mesh(1, 1)):
+        fed = _make_fed(loss_fn, priv, horizon=2, mesh=mesh,
+                        bank_dtype=jnp.bfloat16)
+        s, m = fed.run_rounds(fed.init_state(params), batches, seq,
+                              key=jax.random.PRNGKey(6))
+        runs.append((np.asarray(s.theta_L.buf), np.asarray(m["refused"]),
+                     fed.reconcile(s)))
+    (theta_m, refused_m, led_m), (theta_1, refused_1, led_1) = runs
+    np.testing.assert_array_equal(refused_m, refused_1)
+    assert led_m == led_1
+    np.testing.assert_allclose(theta_m, theta_1, rtol=2e-5, atol=2e-6)
+
+
+def test_sharded_row_gather_is_exact():
+    # The owner-row gather on a bank sharded by rows is a masked sum,
+    # not a dynamic index (which all-gathers the whole bank); it returns
+    # the row's exact values.
+    from repro.federation.deep import _gather_row
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_host_mesh()
+    bank = jax.random.normal(jax.random.PRNGKey(0),
+                             (N_OWNERS, 64)).astype(jnp.bfloat16)
+    sh = flat_shardings(mesh, N_OWNERS, 64)
+    if MULTI_DEVICE:
+        assert sh.bank.spec[0] is not None
+    masked = sh._replace(bank=jax.sharding.NamedSharding(
+        make_debug_mesh(1, 1), jax.sharding.PartitionSpec("data")))
+    for idx in (0, 3, N_OWNERS - 1):
+        for rule in (sh, masked):
+            row = jax.jit(_gather_row, static_argnums=2)(bank, idx, rule)
+            np.testing.assert_array_equal(np.asarray(row, np.float32),
+                                          np.asarray(bank[idx], np.float32))
 
 
 def test_owner_parallel_repeat_dispatches_reuse_compile_cache(toy):

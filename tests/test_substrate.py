@@ -114,9 +114,8 @@ def test_checkpoint_roundtrip(tmp_path, rng_key):
 
 # ------------------------------ sharding rules -----------------------------
 def _abstract_mesh(shape, names):
-    # AbstractMesh takes ((name, size), ...) pairs, not separate tuples.
     from jax.sharding import AbstractMesh
-    return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 @pytest.mark.parametrize("arch", sorted(all_configs()))
