@@ -1,7 +1,6 @@
 """Kernel-path microbenches (CPU): pure-jnp reference implementations at
 small scale + the Pallas kernels in interpret mode for correctness-parity
-timing. Real TPU timing is out of scope for this container — the roofline
-table (bench_roofline) is the perf deliverable."""
+timing. Device timing comes from the chip benchmark (`bench/run.py`)."""
 from __future__ import annotations
 
 import jax

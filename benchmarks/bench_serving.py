@@ -1,6 +1,5 @@
 """Serving-path microbench: batched one-token decode steps/sec on CPU for
-every assigned architecture (reduced configs — the pod-scale numbers are the
-decode rows of bench_roofline)."""
+every assigned architecture (reduced configs)."""
 from __future__ import annotations
 
 import time

@@ -28,7 +28,6 @@ Mapping to the paper:
                            parity overhead + resident-bytes scaling on
                            10k/100k-owner availability traces
     bench_kernels       -> kernel-path microbenches (CPU)
-    bench_roofline      -> §Roofline table from the dry-run artifacts
 """
 from __future__ import annotations
 
@@ -57,14 +56,12 @@ def main() -> None:
                             bench_collaboration, bench_comm_timing,
                             bench_convergence, bench_cop_surface,
                             bench_fused_rounds, bench_kernels,
-                            bench_paged_bank, bench_roofline,
-                            bench_serving)
+                            bench_paged_bank, bench_serving)
 
     suites = {
         "comm_timing": bench_comm_timing.run,
         "kernels": bench_kernels.run,
         "serving": bench_serving.run,
-        "roofline": bench_roofline.run,
         "convergence": (lambda: bench_convergence.run(n_runs=20)) if args.fast
         else bench_convergence.run,
         "cop_surface": bench_cop_surface.run,

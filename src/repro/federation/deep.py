@@ -202,11 +202,12 @@ def _tree_row_of(tree: TreeNoise, owner_idx, row_idx=None):
     the leaf counters stay a per-owner (N,) column). None keeps both
     equal to `owner_idx` — the non-paged trace, verbatim."""
     ridx = owner_idx if row_idx is None else row_idx
-    row = jax.tree_util.tree_map(
-        lambda leaf: jax.lax.dynamic_index_in_dim(leaf, ridx, 0,
-                                                  keepdims=False),
-        tree.nodes)
-    return row, tree.counts[owner_idx]
+    with jax.named_scope("fed.noise"):       # the noise state's own read
+        row = jax.tree_util.tree_map(
+            lambda leaf: jax.lax.dynamic_index_in_dim(leaf, ridx, 0,
+                                                      keepdims=False),
+            tree.nodes)
+        return row, tree.counts[owner_idx]
 
 
 def _tree_write(tree: TreeNoise, new_row, owner_idx, grant=1,
@@ -391,6 +392,20 @@ def _constrain_tree(tr, sh):
         tr.nodes, sh.tree_nodes))
 
 
+def _constrain_state(theta_L, bank, tr, sh):
+    """Pin a round's theta_L, bank and noise tree to the mesh layout
+    (`sh`; None passes them through), each under the scope of the pass
+    that wrote it."""
+    if sh is None:
+        return theta_L, bank, tr
+    with jax.named_scope("fed.noise"):
+        theta_L = _constrain(theta_L, sh.theta)
+        tr = _constrain_tree(tr, sh)
+    with jax.named_scope("fed.bank_write"):
+        bank = _constrain_bank(bank, sh)
+    return theta_L, bank, tr
+
+
 def _require_tree(cfg: AsyncDPConfig, state: AsyncDPState):
     """The state's TreeNoise when cfg asks for one (raising on states
     built before the tree was configured); None otherwise."""
@@ -518,8 +533,9 @@ def _round_math(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array]):
             lambda leaf: jnp.clip(leaf, -cfg.theta_max, cfg.theta_max), tree)
 
     def inner(theta_L, theta_i, batch, owner_idx, key, noise_extra=None):
-        theta_bar = jax.tree_util.tree_map(
-            lambda a, b: 0.5 * (a + b), theta_L, theta_i)             # (6)
+        with jax.named_scope("fed.noise"):
+            theta_bar = jax.tree_util.tree_map(
+                lambda a, b: 0.5 * (a + b), theta_L, theta_i)         # (6)
 
         if noise_extra is None:
             qbar, pm = private_grad(loss_fn, theta_bar, batch, key,
@@ -535,21 +551,23 @@ def _round_math(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array]):
                                           cfg=cfg.privatizer,
                                           noise_scale=scales[owner_idx],
                                           return_noise=True)
-            qbar = jax.tree_util.tree_map(
-                lambda q, e: (q.astype(jnp.float32) + e).astype(q.dtype),
-                qbar, noise_extra)
-        g_reg = jax.tree_util.tree_map(
-            lambda leaf: cfg.sigma * leaf.astype(jnp.float32), theta_bar)   # grad g
-
-        w_i = n_i[owner_idx] / n
-        new_i = project(jax.tree_util.tree_map(
-            lambda tb, gg, q: tb - lr_own * (gg / (2 * N)
-                                             + w_i * q.astype(jnp.float32)
-                                             ).astype(tb.dtype),
-            theta_bar, g_reg, qbar))                                   # (5)
-        new_L = project(jax.tree_util.tree_map(
-            lambda tb, gg: tb - (lr_L * gg).astype(tb.dtype),
-            theta_bar, g_reg))                                         # (7)
+            with jax.named_scope("fed.noise"):
+                qbar = jax.tree_util.tree_map(
+                    lambda q, e: (q.astype(jnp.float32) + e).astype(q.dtype),
+                    qbar, noise_extra)
+        with jax.named_scope("fed.noise"):
+            g_reg = jax.tree_util.tree_map(
+                lambda leaf: cfg.sigma * leaf.astype(jnp.float32),
+                theta_bar)                                         # grad g
+            w_i = n_i[owner_idx] / n
+            new_i = project(jax.tree_util.tree_map(
+                lambda tb, gg, q: tb - lr_own * (gg / (2 * N)
+                                                 + w_i * q.astype(jnp.float32)
+                                                 ).astype(tb.dtype),
+                theta_bar, g_reg, qbar))                               # (5)
+            new_L = project(jax.tree_util.tree_map(
+                lambda tb, gg: tb - (lr_L * gg).astype(tb.dtype),
+                theta_bar, g_reg))                                     # (7)
         metrics = {"clip_frac": pm["clip_frac"],
                    "max_grad_norm": pm["max_grad_norm"],
                    "grad_noise_scale": scales[owner_idx]}
@@ -563,18 +581,22 @@ def _round_math(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array]):
                 "PagedBank needs the flat engine (paging.init_paged_state "
                 "builds ParamFlat states); the pytree path cannot page")
         del row_idx                 # pytree banks index rows by owner
-        theta_i = jax.tree_util.tree_map(
-            lambda leaf: jax.lax.dynamic_index_in_dim(leaf, owner_idx, 0,
-                                                   keepdims=False),
-            bank)
+        with jax.named_scope("fed.bank_read"):
+            theta_i = jax.tree_util.tree_map(
+                lambda leaf: jax.lax.dynamic_index_in_dim(
+                    leaf, owner_idx, 0, keepdims=False), bank)
         # decayed inertia target (staleness.staleness_weight): the round
         # runs against a copy pulled toward theta_L, but the RAW row is
         # what comes back — masked rounds write it back verbatim. The
         # hook is statically absent at decay=1 (verbatim trace).
-        theta_eff = theta_i if stale_w is None else jax.tree_util.tree_map(
-            lambda l, i: (l.astype(jnp.float32) + stale_w
-                          * (i.astype(jnp.float32) - l.astype(jnp.float32))
-                          ).astype(i.dtype), theta_L, theta_i)
+        with jax.named_scope("fed.noise"):
+            theta_eff = (theta_i if stale_w is None else
+                         jax.tree_util.tree_map(
+                             lambda l, i: (l.astype(jnp.float32) + stale_w
+                                           * (i.astype(jnp.float32)
+                                              - l.astype(jnp.float32))
+                                           ).astype(i.dtype),
+                             theta_L, theta_i))
         d = cfg.tree_depth
         if tree_row is None or not d:
             # no tree, or the degenerate depth-0 tree: the round IS the
@@ -588,22 +610,24 @@ def _round_math(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array]):
                 "(init_state_flat) — the pytree path's fused privatizer "
                 "adds its noise in-kernel and cannot split out the draw")
         from repro.kernels.tree_noise.ref import tree_masks_ref
-        retired, fresh = tree_masks_ref(tree_count, d)        # (d,) bools
 
         def bcast(m, leaf):
             return m.reshape((d,) + (1,) * (leaf.ndim - 1))
 
-        extra = jax.tree_util.tree_map(
-            lambda nd: -jnp.sum(jnp.where(bcast(retired, nd), nd, 0.0),
-                                axis=0), tree_row)
+        with jax.named_scope("fed.noise"):
+            retired, fresh = tree_masks_ref(tree_count, d)    # (d,) bools
+            extra = jax.tree_util.tree_map(
+                lambda nd: -jnp.sum(jnp.where(bcast(retired, nd), nd, 0.0),
+                                    axis=0), tree_row)
         new_L, new_i, metrics, zeta = inner(theta_L, theta_eff, batch,
                                             owner_idx, key,
                                             noise_extra=extra)
-        new_row = jax.tree_util.tree_map(
-            lambda nd, z: jnp.where(
-                bcast(fresh, nd), z[None].astype(jnp.float32),
-                jnp.where(bcast(retired, nd), 0.0, nd)),
-            tree_row, zeta)
+        with jax.named_scope("fed.noise"):
+            new_row = jax.tree_util.tree_map(
+                lambda nd, z: jnp.where(
+                    bcast(fresh, nd), z[None].astype(jnp.float32),
+                    jnp.where(bcast(retired, nd), 0.0, nd)),
+                tree_row, zeta)
         return new_L, new_i, theta_i, metrics, new_row
 
     compute.inner = inner
@@ -624,22 +648,27 @@ def _flat_clipped_grad_acc(loss_fn, spec: FlatSpec, pcfg: PrivatizerConfig,
     """
     from repro.kernels.dp_clip_noise.ops import fused_sqnorm_tree
     interp = resolve_interpret(pcfg.kernel_interpret)
-    tb_tree = spec.unpack(tb)
+    with jax.named_scope("fed.grad"):
+        tb_tree = spec.unpack(tb)
 
     def flat_grad(mb):
-        return spec.pack(jax.grad(loss_fn)(tb_tree, mb))   # (P,)
+        with jax.named_scope("fed.grad"):
+            return spec.pack(jax.grad(loss_fn)(tb_tree, mb))   # (P,)
 
-    def sqnorm(g):
-        return fused_sqnorm_tree(g, block_rows=pcfg.kernel_block_rows,
-                                 interpret=interp, mesh=mesh)
+    def clip(g):
+        # (norm, clip scale) of one group's gradient
+        norm = jnp.sqrt(fused_sqnorm_tree(g, block_rows=pcfg.kernel_block_rows,
+                                          interpret=interp, mesh=mesh))
+        return norm, jnp.minimum(1.0, pcfg.xi / jnp.maximum(norm, 1e-12))
 
     if pcfg.granularity == "example":
         B = jax.tree_util.tree_leaves(batch)[0].shape[0]
         grads = jax.vmap(lambda ex: flat_grad(
             jax.tree_util.tree_map(lambda a: a[None], ex)))(batch)  # (B, P)
-        norms = jnp.sqrt(jnp.sum(jnp.square(grads), axis=1))
-        scale = jnp.minimum(1.0, pcfg.xi / jnp.maximum(norms, 1e-12))
-        acc = jnp.sum(grads * scale[:, None], axis=0)
+        with jax.named_scope("fed.clip"):
+            norms = jnp.sqrt(jnp.sum(jnp.square(grads), axis=1))
+            scale = jnp.minimum(1.0, pcfg.xi / jnp.maximum(norms, 1e-12))
+            acc = jnp.sum(grads * scale[:, None], axis=0)
         return acc, 1.0 / B, {
             "clip_frac": jnp.mean((norms > pcfg.xi).astype(jnp.float32)),
             "max_grad_norm": jnp.max(norms)}
@@ -656,23 +685,26 @@ def _flat_clipped_grad_acc(loss_fn, spec: FlatSpec, pcfg: PrivatizerConfig,
         mb = (jax.tree_util.tree_map(lambda a: a[0], batch)
               if pcfg.pre_grouped else batch)
         g = flat_grad(mb)
-        norm = jnp.sqrt(sqnorm(g))
-        s = jnp.minimum(1.0, pcfg.xi / jnp.maximum(norm, 1e-12))
-        return g * s, 1.0, {
+        with jax.named_scope("fed.clip"):
+            norm, s = clip(g)
+            acc = g * s
+        return acc, 1.0, {
             "clip_frac": (norm > pcfg.xi).astype(jnp.float32),
             "max_grad_norm": norm}
 
     def body(carry, mb):
         acc, nclip, mx = carry
         g = flat_grad(mb)
-        norm = jnp.sqrt(sqnorm(g))
-        s = jnp.minimum(1.0, pcfg.xi / jnp.maximum(norm, 1e-12))
-        return (acc + g * s, nclip + (norm > pcfg.xi),
-                jnp.maximum(mx, norm)), None
+        with jax.named_scope("fed.clip"):
+            norm, s = clip(g)
+            return (acc + g * s, nclip + (norm > pcfg.xi),
+                    jnp.maximum(mx, norm)), None
 
     xs = batch if pcfg.pre_grouped else _group_batch(batch, G)
+    with jax.named_scope("fed.clip"):
+        acc0 = jnp.zeros_like(tb)
     (acc, nclip, mx), _ = jax.lax.scan(
-        body, (jnp.zeros_like(tb), jnp.zeros((), jnp.float32),
+        body, (acc0, jnp.zeros((), jnp.float32),
                jnp.zeros((), jnp.float32)), xs)
     return acc, 1.0 / G, {"clip_frac": nclip / G, "max_grad_norm": mx}
 
@@ -716,90 +748,102 @@ def _round_math_flat(loss_fn, cfg: AsyncDPConfig, scales: Optional[jax.Array],
         # (ridx IS owner_idx). Scales/weights always index by owner.
         hot = bank.hot if isinstance(bank, PagedBank) else bank
         ridx = owner_idx if row_idx is None else row_idx
-        if isinstance(hot, QuantBank):
-            theta_i = _decode_bank_row(hot, ridx, pcfg)            # (P,)
-        else:
-            theta_i = _gather_row(hot, ridx, sh)                    # (P,)
-        if sh is not None:
-            # the gathered row keeps the bank's P-axis layout (== theta's),
-            # so theta_bar and the whole round stay local in P
-            theta_i = jax.lax.with_sharding_constraint(theta_i, sh.row)
+        with jax.named_scope("fed.bank_read"):
+            if isinstance(hot, QuantBank):
+                theta_i = _decode_bank_row(hot, ridx, pcfg)        # (P,)
+            else:
+                theta_i = _gather_row(hot, ridx, sh)                # (P,)
+            if sh is not None:
+                # the gathered row keeps the bank's P-axis layout (==
+                # theta's), so theta_bar and the whole round stay local
+                theta_i = jax.lax.with_sharding_constraint(theta_i, sh.row)
         # decayed inertia target — same contract as the pytree path: the
         # round consumes the pulled-in copy, the RAW row is returned for
         # the masked write-backs. Statically absent at decay=1.
-        theta_eff = (theta_i if stale_w is None
-                     else theta_L.buf + stale_w * (theta_i - theta_L.buf))
+        with jax.named_scope("fed.noise"):
+            theta_eff = (theta_i if stale_w is None
+                         else theta_L.buf + stale_w * (theta_i
+                                                       - theta_L.buf))
         if pcfg.fused_kernel:
             if pcfg.mechanism != "laplace":
                 raise ValueError(
                     "fused_kernel implements the laplace mechanism")
             from repro.kernels.dp_clip_noise.ops import dp_round_flat
-            tb = 0.5 * (theta_L.buf + theta_eff)                   # (6)
-            ns = scales[owner_idx]
+            with jax.named_scope("fed.noise"):
+                tb = 0.5 * (theta_L.buf + theta_eff)               # (6)
+                ns = scales[owner_idx]
             acc, gain, pm = _flat_clipped_grad_acc(loss_fn, spec, pcfg,
                                                    tb, batch, mesh)
-            if tree_on:
-                # tree mechanism: the round key feeds ONLY the tree op
-                # (the fresh node draw); the response adds the node
-                # DELTA, then the epilogue repeats dp_round_ref's exact
-                # op order so depth-0 (no node traffic, delta == draw)
-                # stays bit-identical to the dp_round_flat path.
-                from repro.kernels.tree_noise.ops import tree_delta_row
-                delta, new_row = tree_delta_row(
-                    tree_row, tree_count, key, ns,
-                    block_rows=min(pcfg.kernel_block_rows, 64),
-                    interpret=resolve_interpret(pcfg.kernel_interpret))
-                q = acc * gain + delta                              # (4)
-                g_reg = cfg.sigma * tb
-                new_i = jnp.clip(
-                    tb - lr_own * (g_reg * (1.0 / (2 * N))
-                                   + (n_i[owner_idx] / n) * q),
-                    -cfg.theta_max, cfg.theta_max)                  # (5)
-                new_L = jnp.clip(tb - lr_L * g_reg,
-                                 -cfg.theta_max, cfg.theta_max)     # (7)
-            else:
-                new_L, new_i = dp_round_flat(              # (4)+(5)+(7)+Pi
-                    tb, acc, key, gain, ns, n_i[owner_idx] / n,
-                    sigma=cfg.sigma, lr_own=lr_own, lr_l=lr_L, n_owners=N,
-                    theta_max=cfg.theta_max,
-                    block_rows=pcfg.kernel_block_rows,
-                    interpret=resolve_interpret(pcfg.kernel_interpret),
-                    mesh=mesh)
-                new_row = tree_row
+            with jax.named_scope("fed.noise"):
+                if tree_on:
+                    # tree mechanism: the round key feeds ONLY the tree op
+                    # (the fresh node draw); the response adds the node
+                    # DELTA, then the epilogue repeats dp_round_ref's
+                    # exact op order so depth-0 (no node traffic, delta
+                    # == draw) stays bit-identical to the dp_round_flat
+                    # path.
+                    from repro.kernels.tree_noise.ops import tree_delta_row
+                    delta, new_row = tree_delta_row(
+                        tree_row, tree_count, key, ns,
+                        block_rows=min(pcfg.kernel_block_rows, 64),
+                        interpret=resolve_interpret(pcfg.kernel_interpret))
+                    q = acc * gain + delta                          # (4)
+                    g_reg = cfg.sigma * tb
+                    new_i = jnp.clip(
+                        tb - lr_own * (g_reg * (1.0 / (2 * N))
+                                       + (n_i[owner_idx] / n) * q),
+                        -cfg.theta_max, cfg.theta_max)              # (5)
+                    new_L = jnp.clip(tb - lr_L * g_reg,
+                                     -cfg.theta_max, cfg.theta_max)  # (7)
+                else:
+                    new_L, new_i = dp_round_flat(          # (4)+(5)+(7)+Pi
+                        tb, acc, key, gain, ns, n_i[owner_idx] / n,
+                        sigma=cfg.sigma, lr_own=lr_own, lr_l=lr_L,
+                        n_owners=N, theta_max=cfg.theta_max,
+                        block_rows=pcfg.kernel_block_rows,
+                        interpret=resolve_interpret(pcfg.kernel_interpret),
+                        mesh=mesh)
+                    new_row = tree_row
             metrics = {"clip_frac": pm["clip_frac"],
                        "max_grad_norm": pm["max_grad_norm"],
                        "grad_noise_scale": ns}
         else:
-            if tree_on:
-                from repro.kernels.tree_noise.ref import tree_masks_ref
-                retired, fresh = tree_masks_ref(tree_count, d)  # (d,) bool
-                extra = spec.unpack_f32(
-                    -jnp.sum(jnp.where(retired[:, None], tree_row, 0.0),
-                             axis=0))
-            else:
-                extra = None
-            try:
-                tl_tree, ti_tree = jax.lax.optimization_barrier(
-                    (spec.unpack(theta_L.buf), spec.unpack(theta_eff)))
-            except NotImplementedError:
-                # no batching rule for the barrier (vmapped by the
-                # owner-parallel grouped driver). The barrier is
-                # semantically identity — only an anti-fusion hint that
-                # protects the scan-carry BIT-parity contract, which the
-                # grouped mode does not promise for groups > 1 anyway.
-                tl_tree, ti_tree = (spec.unpack(theta_L.buf),
-                                    spec.unpack(theta_eff))
+            # reference mode: the unpack, pack and node bookkeeping around
+            # tree_inner are the round's own passes; tree_inner scopes
+            # its gradient, clipping and noise itself
+            with jax.named_scope("fed.noise"):
+                if tree_on:
+                    from repro.kernels.tree_noise.ref import tree_masks_ref
+                    retired, fresh = tree_masks_ref(tree_count, d)
+                    extra = spec.unpack_f32(
+                        -jnp.sum(jnp.where(retired[:, None], tree_row, 0.0),
+                                 axis=0))
+                else:
+                    extra = None
+                try:
+                    tl_tree, ti_tree = jax.lax.optimization_barrier(
+                        (spec.unpack(theta_L.buf), spec.unpack(theta_eff)))
+                except NotImplementedError:
+                    # no batching rule for the barrier (vmapped by the
+                    # owner-parallel grouped driver). The barrier is
+                    # semantically identity — only an anti-fusion hint
+                    # that protects the scan-carry BIT-parity contract,
+                    # which the grouped mode does not promise for groups
+                    # > 1 anyway.
+                    tl_tree, ti_tree = (spec.unpack(theta_L.buf),
+                                        spec.unpack(theta_eff))
             new_L_t, new_i_t, metrics, zeta = tree_inner(
                 tl_tree, ti_tree, batch, owner_idx, key,
                 noise_extra=extra)
-            new_L, new_i = spec.pack(new_L_t), spec.pack(new_i_t)
-            if tree_on:
-                zf = spec.pack_f32(zeta)
-                new_row = jnp.where(fresh[:, None], zf[None],
-                                    jnp.where(retired[:, None], 0.0,
-                                              tree_row))
-            else:
-                new_row = tree_row
+            with jax.named_scope("fed.noise"):
+                new_L, new_i = spec.pack(new_L_t), spec.pack(new_i_t)
+                if tree_on:
+                    zf = spec.pack_f32(zeta)
+                    new_row = jnp.where(fresh[:, None], zf[None],
+                                        jnp.where(retired[:, None], 0.0,
+                                                  tree_row))
+                else:
+                    new_row = tree_row
         return ParamFlat(new_L, spec), new_i, theta_i, metrics, new_row
 
     return compute
@@ -934,46 +978,49 @@ def _guarded_round(compute, cfg: AsyncDPConfig, state: AsyncDPState,
                                                             row_idx)
     # payload integrity is judged on the PRE-ROUND bank (what the round
     # actually consumed), before any write
-    payload_ok = _faults.verify_row(fs.checksum, state.bank, owner_idx,
-                                    fcode == _faults.CORRUPT_PAYLOAD,
-                                    row_idx=row_idx)
+    with jax.named_scope("fed.bank_read"):
+        payload_ok = _faults.verify_row(fs.checksum, state.bank, owner_idx,
+                                        fcode == _faults.CORRUPT_PAYLOAD,
+                                        row_idx=row_idx)
     new_L, new_i, theta_i, metrics, new_row = compute(
         state.theta_L, state.bank, batch, owner_idx, key,
         tree_row=row, tree_count=cnt, row_idx=row_idx, stale_w=stale_w)
-    new_i = _faults.inject_nonfinite(new_i, fcode == _faults.NONFINITE_GRAD)
-    guard_ok = (payload_ok & _faults.finite_guard((new_i, new_L))
-                & (fcode != _faults.STALE))
-    on_time = deadline_guard(fcode)
-    apply = answered & guard_ok & on_time
-    timed = answered & ~on_time
-    guard_rej = answered & on_time & ~guard_ok
-    theta_L = jax.tree_util.tree_map(
-        lambda nl, ol: jnp.where(apply, nl, ol), new_L, state.theta_L)
-    if _bank_is_quant(state.bank):
-        # same key as compute() by contract: _quant_write folds in
-        # _CODEC_SALT, so SR bits never touch the privacy stream
-        bank = _quant_write(state.bank, new_i, widx, key,  # dpcheck: ignore[DPC105]
-                            cfg.privatizer, ok=apply)
-    else:
-        bank = _write_bank(
-            state.bank,
-            jax.tree_util.tree_map(lambda a, b: jnp.where(apply, a, b),
-                                   new_i, theta_i),
-            widx)
+    with jax.named_scope("fed.noise"):
+        new_i = _faults.inject_nonfinite(new_i,
+                                         fcode == _faults.NONFINITE_GRAD)
+        guard_ok = (payload_ok & _faults.finite_guard((new_i, new_L))
+                    & (fcode != _faults.STALE))
+        on_time = deadline_guard(fcode)
+        apply = answered & guard_ok & on_time
+        timed = answered & ~on_time
+        guard_rej = answered & on_time & ~guard_ok
+        theta_L = jax.tree_util.tree_map(
+            lambda nl, ol: jnp.where(apply, nl, ol), new_L, state.theta_L)
+    with jax.named_scope("fed.bank_write"):
+        if _bank_is_quant(state.bank):
+            # same key as compute() by contract: _quant_write folds in
+            # _CODEC_SALT, so SR bits never touch the privacy stream
+            bank = _quant_write(state.bank, new_i, widx, key,  # dpcheck: ignore[DPC105]
+                                cfg.privatizer, ok=apply)
+        else:
+            bank = _write_bank(
+                state.bank,
+                jax.tree_util.tree_map(lambda a, b: jnp.where(apply, a, b),
+                                       new_i, theta_i),
+                widx)
     if tr is not None:
-        masked_row = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(apply, a, b), new_row, row)
-        tr = _tree_write(tr, masked_row, owner_idx,
-                         grant=apply.astype(jnp.int32), row_idx=row_idx)
-    if sh is not None:
-        theta_L = _constrain(theta_L, sh.theta)
-        bank = _constrain_bank(bank, sh)
-        tr = _constrain_tree(tr, sh)
+        with jax.named_scope("fed.noise"):
+            masked_row = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(apply, a, b), new_row, row)
+            tr = _tree_write(tr, masked_row, owner_idx,
+                             grant=apply.astype(jnp.int32), row_idx=row_idx)
+    theta_L, bank, tr = _constrain_state(theta_L, bank, tr, sh)
     # re-derive the stored checksum from the POST-WRITE row; masked
     # rounds drop the scatter, so the stored sum stays in lockstep with
     # the row it describes
-    fs = _faults.update_checksum(fs, bank, owner_idx, apply,
-                                 row_idx=row_idx)
+    with jax.named_scope("fed.bank_write"):
+        fs = _faults.update_checksum(fs, bank, owner_idx, apply,
+                                     row_idx=row_idx)
     metrics = dict(metrics)
     metrics.update(faulted=guard_rej, timed_out=timed)
     return theta_L, bank, tr, fs, metrics, apply, guard_rej, timed
@@ -1000,7 +1047,8 @@ def make_train_step(loss_fn, cfg: AsyncDPConfig,
              fault_code=None) -> Tuple[AsyncDPState, Dict]:
         tr = _require_tree(cfg, state)
         sh = _flat_shardings_for(mesh, state.theta_L, state.bank)
-        slot, hit = _bank_slot(state.bank, owner_idx)
+        with jax.named_scope("fed.bank_read"):
+            slot, hit = _bank_slot(state.bank, owner_idx)
         if state.faults is not None:
             # fault-armed state: host-side the session has already
             # handled DROP and quarantine (neither reaches the step), so
@@ -1045,34 +1093,35 @@ def make_train_step(loss_fn, cfg: AsyncDPConfig,
         if hit is not None:
             # paged, host-authorized: no ledger in-graph, so residency is
             # the only grant bit — a miss masks every write bit-exactly
-            new_L = jax.tree_util.tree_map(
-                lambda nl, ol: jnp.where(hit, nl, ol), new_L,
-                state.theta_L)
+            with jax.named_scope("fed.noise"):
+                new_L = jax.tree_util.tree_map(
+                    lambda nl, ol: jnp.where(hit, nl, ol), new_L,
+                    state.theta_L)
         widx = owner_idx if slot is None else slot
-        if _bank_is_quant(state.bank):
-            # same key as compute() by contract: _quant_write folds in
-            # _CODEC_SALT, so SR bits never touch the privacy stream
-            bank = _quant_write(state.bank, new_i, widx, key,  # dpcheck: ignore[DPC105]
-                                cfg.privatizer, ok=hit)
-        else:
-            value = (new_i if hit is None
-                     else jnp.where(hit, new_i, theta_i))
-            bank = _write_bank(state.bank, value, widx)
+        with jax.named_scope("fed.bank_write"):
+            if _bank_is_quant(state.bank):
+                # same key as compute() by contract: _quant_write folds in
+                # _CODEC_SALT, so SR bits never touch the privacy stream
+                bank = _quant_write(state.bank, new_i, widx, key,  # dpcheck: ignore[DPC105]
+                                    cfg.privatizer, ok=hit)
+            else:
+                value = (new_i if hit is None
+                         else jnp.where(hit, new_i, theta_i))
+                bank = _write_bank(state.bank, value, widx)
         if tr is not None:
             # host-authorized path: the round always counts (refusal
             # happens before step() is called), so the leaf lands unless
             # a paged state missed
-            if hit is None:
-                tr = _tree_write(tr, new_row, owner_idx)
-            else:
-                masked_row = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(hit, a, b), new_row, row)
-                tr = _tree_write(tr, masked_row, owner_idx,
-                                 grant=hit.astype(jnp.int32), row_idx=slot)
-        if sh is not None:
-            new_L = _constrain(new_L, sh.theta)
-            bank = _constrain_bank(bank, sh)
-            tr = _constrain_tree(tr, sh)
+            with jax.named_scope("fed.noise"):
+                if hit is None:
+                    tr = _tree_write(tr, new_row, owner_idx)
+                else:
+                    masked_row = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(hit, a, b), new_row, row)
+                    tr = _tree_write(tr, masked_row, owner_idx,
+                                     grant=hit.astype(jnp.int32),
+                                     row_idx=slot)
+        new_L, bank, tr = _constrain_state(new_L, bank, tr, sh)
         bump = 1 if hit is None else hit.astype(jnp.int32)
         return AsyncDPState(new_L, bank, state.step + bump,
                             state.ledger, tr, state.faults,
@@ -1117,49 +1166,53 @@ def make_fused_rounds(loss_fn, cfg: AsyncDPConfig,
         led = state.ledger
         tr = state.tree
         sh = _flat_shardings_for(mesh, state.theta_L, state.bank)
-        slot, hit = _bank_slot(state.bank, owner_idx)
-        ok = led.authorized(owner_idx)
-        if hit is not None:
-            # paged: residency folds into the grant BEFORE the ledger
-            # update — a miss spends nothing and lands in `refused`
-            # (lawful: no epsilon without a response; in a correctly
-            # prefetched session misses never occur, so a nonzero
-            # refused count under an authorized schedule flags a pager
-            # bug, not a privacy event)
-            ok = ok & hit
-        oki = ok.astype(jnp.int32)
+        with jax.named_scope("fed.bank_read"):
+            slot, hit = _bank_slot(state.bank, owner_idx)
+        with jax.named_scope("fed.ledger"):
+            ok = led.authorized(owner_idx)
+            if hit is not None:
+                # paged: residency folds into the grant BEFORE the ledger
+                # update — a miss spends nothing and lands in `refused`
+                # (lawful: no epsilon without a response; in a correctly
+                # prefetched session misses never occur, so a nonzero
+                # refused count under an authorized schedule flags a
+                # pager bug, not a privacy event)
+                ok = ok & hit
+            oki = ok.astype(jnp.int32)
         row, cnt = (None, None) if tr is None else _tree_row_of(tr,
                                                                 owner_idx,
                                                                 slot)
         new_L, new_i, theta_i, metrics, new_row = compute(
             state.theta_L, state.bank, batch, owner_idx, key,
             tree_row=row, tree_count=cnt, row_idx=slot)
-        theta_L = jax.tree_util.tree_map(
-            lambda nl, ol: jnp.where(ok, nl, ol), new_L, state.theta_L)
+        with jax.named_scope("fed.noise"):
+            theta_L = jax.tree_util.tree_map(
+                lambda nl, ol: jnp.where(ok, nl, ol), new_L, state.theta_L)
         widx = owner_idx if slot is None else slot
-        if _bank_is_quant(state.bank):
-            bank = _quant_write(state.bank, new_i, widx, key,
-                                cfg.privatizer, ok=ok)
-        else:
-            bank = _write_bank(
-                state.bank,
-                jax.tree_util.tree_map(lambda a, b: jnp.where(ok, a, b),
-                                       new_i, theta_i),
-                widx)
+        with jax.named_scope("fed.bank_write"):
+            if _bank_is_quant(state.bank):
+                bank = _quant_write(state.bank, new_i, widx, key,
+                                    cfg.privatizer, ok=ok)
+            else:
+                bank = _write_bank(
+                    state.bank,
+                    jax.tree_util.tree_map(lambda a, b: jnp.where(ok, a, b),
+                                           new_i, theta_i),
+                    widx)
         if tr is not None:
             # refusal masking: the old row is written back and the leaf
             # counter bumps by the grant bit, so a refused round is a
             # bit-exact no-op on the tree (same contract as the bank)
-            masked_row = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(ok, a, b), new_row, row)
-            tr = _tree_write(tr, masked_row, owner_idx, grant=oki,
-                             row_idx=slot)
-        if sh is not None:
-            theta_L = _constrain(theta_L, sh.theta)
-            bank = _constrain_bank(bank, sh)
-            tr = _constrain_tree(tr, sh)
-        ledger = led.replace(spent=led.spent.at[owner_idx].add(oki),
-                             refused=led.refused.at[owner_idx].add(1 - oki))
+            with jax.named_scope("fed.noise"):
+                masked_row = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(ok, a, b), new_row, row)
+                tr = _tree_write(tr, masked_row, owner_idx, grant=oki,
+                                 row_idx=slot)
+        theta_L, bank, tr = _constrain_state(theta_L, bank, tr, sh)
+        with jax.named_scope("fed.ledger"):
+            ledger = led.replace(
+                spent=led.spent.at[owner_idx].add(oki),
+                refused=led.refused.at[owner_idx].add(1 - oki))
         metrics = dict(metrics)
         metrics.update(refused=~ok, owner=owner_idx)
         return AsyncDPState(theta_L, bank, state.step + oki, ledger,
@@ -1184,14 +1237,16 @@ def make_fused_rounds(loss_fn, cfg: AsyncDPConfig,
         policy = cfg.fault_policy
         spolicy = cfg.staleness
         sh = _flat_shardings_for(mesh, state.theta_L, state.bank)
-        slot, hit = _bank_slot(state.bank, owner_idx)
+        with jax.named_scope("fed.bank_read"):
+            slot, hit = _bank_slot(state.bank, owner_idx)
         quar = fs.quarantined[owner_idx]
-        led_auth = led.authorized(owner_idx)
-        if hit is not None:
-            # paged: a page miss refuses like budget exhaustion (spends
-            # nothing, counts in `refused` unless quarantined) — see the
-            # plain body
-            led_auth = led_auth & hit
+        with jax.named_scope("fed.ledger"):
+            led_auth = led.authorized(owner_idx)
+            if hit is not None:
+                # paged: a page miss refuses like budget exhaustion
+                # (spends nothing, counts in `refused` unless
+                # quarantined) — see the plain body
+                led_auth = led_auth & hit
         if ss is not None:
             in_backoff = ss.cooldown[owner_idx] > 0
             is_retry = ~quar & in_backoff
@@ -1209,22 +1264,24 @@ def make_fused_rounds(loss_fn, cfg: AsyncDPConfig,
             _guarded_round(compute, cfg, state, batch, owner_idx, key,
                            fcode, answered, sh, row_idx=slot,
                            stale_w=stale_w)
-        upd = dict(
-            spent=led.spent.at[owner_idx].add(answered.astype(jnp.int32)),
-            refused=led.refused.at[owner_idx].add(
-                (avail & ~led_auth).astype(jnp.int32)),
-            dropped=led.dropped.at[owner_idx].add(
-                (auth & is_drop).astype(jnp.int32)),
-            faulted=led.faulted.at[owner_idx].add(
-                guard_rej.astype(jnp.int32)),
-            quarantined=led.quarantined.at[owner_idx].add(
-                quar.astype(jnp.int32)),
-            timed_out=led.timed_out.at[owner_idx].add(
-                timed.astype(jnp.int32)))
-        if ss is not None:
-            upd["retried"] = led.retried.at[owner_idx].add(
-                is_retry.astype(jnp.int32))
-        ledger = led.replace(**upd)
+        with jax.named_scope("fed.ledger"):
+            upd = dict(
+                spent=led.spent.at[owner_idx].add(
+                    answered.astype(jnp.int32)),
+                refused=led.refused.at[owner_idx].add(
+                    (avail & ~led_auth).astype(jnp.int32)),
+                dropped=led.dropped.at[owner_idx].add(
+                    (auth & is_drop).astype(jnp.int32)),
+                faulted=led.faulted.at[owner_idx].add(
+                    guard_rej.astype(jnp.int32)),
+                quarantined=led.quarantined.at[owner_idx].add(
+                    quar.astype(jnp.int32)),
+                timed_out=led.timed_out.at[owner_idx].add(
+                    timed.astype(jnp.int32)))
+            if ss is not None:
+                upd["retried"] = led.retried.at[owner_idx].add(
+                    is_retry.astype(jnp.int32))
+            ledger = led.replace(**upd)
         # timeouts and retries are NOT quarantine events: slowness has
         # its own escalation path (backoff); a backed-off round is not
         # even a contact (the learner never dispatched)
@@ -1410,22 +1467,93 @@ def make_group_rounds(loss_fn, cfg: AsyncDPConfig,
         return (jnp.where(resident, slots, bank.n_hot),
                 jnp.where(resident, slots, 0))
 
+    def write_rows(bank, new_i, theta_i, keys_g, grant, idx_w, idx_c,
+                   slots):
+        """Scatter the group's bank rows; members without `grant`
+        (refused, padded, guard-rejected) write their own row back
+        unchanged."""
+        hot = bank.hot if slots is not None else bank
+        with jax.named_scope("fed.bank_write"):
+            if isinstance(hot, QuantBank):
+                # error feedback under member-parallelism: members chain
+                # the shared residual IN ROUND ORDER (groups are
+                # consecutive runs of the schedule), exactly as the fused
+                # scan would — encode every member against the carried
+                # residual, advance the carry only on a grant.
+                # Bit-identical to the sequential driver; a fully-refused
+                # group leaves the residual untouched.
+                def _ef_chain(res, inp):
+                    v, k, g = inp
+                    c_n, s_n, err = _encode_bank_row(hot, v + res, k,
+                                                     cfg.privatizer)
+                    return jnp.where(g, err, res), (c_n, s_n)
+
+                residual, (codes_n, scales_n) = jax.lax.scan(
+                    _ef_chain, hot.residual, (new_i, keys_g, grant))
+                codes_w = jnp.where(_member_mask(grant, codes_n), codes_n,
+                                    hot.codes[idx_c])
+                scales_w = jnp.where(grant[:, None], scales_n,
+                                     hot.scales[idx_c])
+                new_hot = QuantBank(
+                    hot.codes.at[idx_w].set(codes_w, mode="drop"),
+                    hot.scales.at[idx_w].set(scales_w, mode="drop"),
+                    residual, hot.codec)
+            else:
+                rows = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(_member_mask(grant, a), a, b),
+                    new_i, theta_i)
+                new_hot = _write_bank_rows(hot, rows, idx_w)
+        return bank.replace(hot=new_hot) if slots is not None else new_hot
+
+    def write_tree(tr, new_rows, rows_t, grant, idx_w, owners_w):
+        """Scatter the members' noise-tree rows (refused/padded members
+        scatter their own row back unchanged) and bump the leaf counters
+        by the grant bits."""
+        if tr is None:
+            return None
+        with jax.named_scope("fed.noise"):
+            rows_m = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(_member_mask(grant, a), a, b),
+                new_rows, rows_t)
+            nodes = jax.tree_util.tree_map(
+                lambda leaf, v: leaf.at[idx_w].set(v, mode="drop"),
+                tr.nodes, rows_m)
+            return tr.replace(nodes=nodes,
+                              counts=tr.counts.at[owners_w].add(
+                                  grant.astype(jnp.int32), mode="drop"))
+
+    def reduce_theta_L(new_L, theta_L, grant):
+        """The single inertia reduction: the mean of the granted
+        members' eq.(7) targets (theta_L unchanged if none is granted)."""
+        with jax.named_scope("fed.noise"):
+            n_ok = jnp.sum(grant.astype(jnp.float32))
+            denom = jnp.maximum(n_ok, 1.0)
+
+            def reduce(stacked, base):
+                s = jnp.sum(jnp.where(_member_mask(grant, stacked), stacked,
+                                      jnp.zeros_like(stacked)),
+                            axis=0) / denom
+                return jnp.where(n_ok > 0, s.astype(base.dtype), base)
+
+            return jax.tree_util.tree_map(reduce, new_L, theta_L)
+
     def body(state: AsyncDPState, xs):
         batch_g, owners, keys_g, valid = xs
         led = state.ledger
         tr = state.tree
         sh = _flat_shardings_for(mesh, state.theta_L, state.bank)
         theta_L, bank = state.theta_L, state.bank
-        if isinstance(bank, PagedBank):
-            slots, hit_g = jax.vmap(bank.lookup)(owners)       # (G,)
-            # residency folds into the grant BEFORE the ledger update —
-            # a miss spends nothing and lands in `refused` (see the
-            # fused driver's body)
-            ok = jax.vmap(led.authorized)(owners) & valid & hit_g
-        else:
-            slots, hit_g = None, None
+        with jax.named_scope("fed.bank_read"):
+            slots, hit_g = (jax.vmap(bank.lookup)(owners)      # (G,)
+                            if isinstance(bank, PagedBank) else (None, None))
+        with jax.named_scope("fed.ledger"):
             ok = jax.vmap(led.authorized)(owners) & valid      # (G,)
-        oki = ok.astype(jnp.int32)
+            if hit_g is not None:
+                # residency folds into the grant BEFORE the ledger
+                # update — a miss spends nothing and lands in `refused`
+                # (see the fused driver's body)
+                ok = ok & hit_g
+            oki = ok.astype(jnp.int32)
 
         # fully-invalid groups are jit-cache shape padding only; the
         # dynamic trip count in run() means they never reach this body,
@@ -1435,69 +1563,16 @@ def make_group_rounds(loss_fn, cfg: AsyncDPConfig,
 
         owners_w = jnp.where(valid, owners, n_owners)          # pad -> drop
         idx_w, idx_c = scatter_indices(bank, owners, valid, slots, hit_g)
-        n_ok = jnp.sum(ok.astype(jnp.float32))
-        denom = jnp.maximum(n_ok, 1.0)
-        hot = bank.hot if slots is not None else bank
-        if isinstance(hot, QuantBank):
-            # error feedback under member-parallelism: members chain the
-            # shared residual IN ROUND ORDER (groups are consecutive runs
-            # of the schedule), exactly as the fused scan would — encode
-            # every member against the carried residual, advance the
-            # carry only on a grant. Bit-identical to the sequential
-            # driver; a fully-refused group leaves the residual untouched.
-            def _ef_chain(res, inp):
-                v, k, grant = inp
-                c_n, s_n, err = _encode_bank_row(hot, v + res, k,
-                                                 cfg.privatizer)
-                return jnp.where(grant, err, res), (c_n, s_n)
-
-            residual, (codes_n, scales_n) = jax.lax.scan(
-                _ef_chain, hot.residual, (new_i, keys_g, ok))
-            codes_w = jnp.where(_member_mask(ok, codes_n), codes_n,
-                                hot.codes[idx_c])
-            scales_w = jnp.where(ok[:, None], scales_n,
-                                 hot.scales[idx_c])
-            new_hot = QuantBank(
-                hot.codes.at[idx_w].set(codes_w, mode="drop"),
-                hot.scales.at[idx_w].set(scales_w, mode="drop"),
-                residual, hot.codec)
-        else:
-            # refused/padded members write their own row back unchanged
-            rows = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(_member_mask(ok, a), a, b),
-                new_i, theta_i)
-            new_hot = _write_bank_rows(hot, rows, idx_w)
-        bank = (bank.replace(hot=new_hot) if slots is not None
-                else new_hot)
-
-        if tr is not None:
-            # refused/padded members scatter their own row back unchanged
-            rows_m = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(_member_mask(ok, a), a, b),
-                new_rows, rows_t)
-            nodes = jax.tree_util.tree_map(
-                lambda leaf, v: leaf.at[idx_w].set(v, mode="drop"),
-                tr.nodes, rows_m)
-            tr = tr.replace(nodes=nodes,
-                            counts=tr.counts.at[owners_w].add(
-                                oki, mode="drop"))
-
-        # single inertia reduction: mean of the granted eq.(7) targets
-
-        def reduce_theta(stacked, base):
-            s = jnp.sum(jnp.where(_member_mask(ok, stacked), stacked,
-                                  jnp.zeros_like(stacked)), axis=0) / denom
-            return jnp.where(n_ok > 0, s.astype(base.dtype), base)
-
-        theta_L = jax.tree_util.tree_map(reduce_theta, new_L, theta_L)
-        if sh is not None:
-            theta_L = _constrain(theta_L, sh.theta)
-            bank = _constrain_bank(bank, sh)
-            tr = _constrain_tree(tr, sh)
-        ledger = led.replace(
-            spent=led.spent.at[owners_w].add(oki, mode="drop"),
-            refused=led.refused.at[owners_w].add(
-                (valid & ~ok).astype(jnp.int32), mode="drop"))
+        bank = write_rows(bank, new_i, theta_i, keys_g, ok, idx_w, idx_c,
+                          slots)
+        tr = write_tree(tr, new_rows, rows_t, ok, idx_w, owners_w)
+        theta_L = reduce_theta_L(new_L, theta_L, ok)
+        theta_L, bank, tr = _constrain_state(theta_L, bank, tr, sh)
+        with jax.named_scope("fed.ledger"):
+            ledger = led.replace(
+                spent=led.spent.at[owners_w].add(oki, mode="drop"),
+                refused=led.refused.at[owners_w].add(
+                    (valid & ~ok).astype(jnp.int32), mode="drop"))
         metrics = dict(metrics)
         metrics.update(refused=~ok, owner=owners)
         return AsyncDPState(theta_L, bank, state.step + jnp.sum(oki),
@@ -1520,14 +1595,15 @@ def make_group_rounds(loss_fn, cfg: AsyncDPConfig,
         tr = state.tree
         sh = _flat_shardings_for(mesh, state.theta_L, state.bank)
         theta_L, bank = state.theta_L, state.bank
-        led_auth = jax.vmap(led.authorized)(owners)
-        if isinstance(bank, PagedBank):
-            slots, hit_g = jax.vmap(bank.lookup)(owners)       # (G,)
-            # a page miss refuses like budget exhaustion (see the fused
-            # driver's faulted body)
-            led_auth = led_auth & hit_g
-        else:
-            slots, hit_g = None, None
+        with jax.named_scope("fed.bank_read"):
+            slots, hit_g = (jax.vmap(bank.lookup)(owners)      # (G,)
+                            if isinstance(bank, PagedBank) else (None, None))
+        with jax.named_scope("fed.ledger"):
+            led_auth = jax.vmap(led.authorized)(owners)
+            if hit_g is not None:
+                # a page miss refuses like budget exhaustion (see the
+                # fused driver's faulted body)
+                led_auth = led_auth & hit_g
         quar = fs.quarantined[owners]
         if ss is not None:
             # each member's round position within the dispatch: groups
@@ -1551,22 +1627,25 @@ def make_group_rounds(loss_fn, cfg: AsyncDPConfig,
         stale_w = None
         if ss is not None and spolicy.decay != 1.0:
             stale_w = staleness_weight(ss, owners, t_g, spolicy)
-        if slots is None:
-            payload_ok = jax.vmap(
-                lambda o, c: _faults.verify_row(fs.checksum, bank, o, c))(
-                owners, fcodes_g == _faults.CORRUPT_PAYLOAD)
-        else:
-            payload_ok = jax.vmap(
-                lambda o, c, s: _faults.verify_row(fs.checksum, bank, o,
-                                                   c, row_idx=s))(
-                owners, fcodes_g == _faults.CORRUPT_PAYLOAD, slots)
+        with jax.named_scope("fed.bank_read"):
+            if slots is None:
+                payload_ok = jax.vmap(
+                    lambda o, c: _faults.verify_row(fs.checksum, bank, o,
+                                                    c))(
+                    owners, fcodes_g == _faults.CORRUPT_PAYLOAD)
+            else:
+                payload_ok = jax.vmap(
+                    lambda o, c, s: _faults.verify_row(fs.checksum, bank, o,
+                                                       c, row_idx=s))(
+                    owners, fcodes_g == _faults.CORRUPT_PAYLOAD, slots)
 
         new_L, new_i, theta_i, metrics, new_rows, rows_t = vmap_rounds(
             theta_L, bank, tr, batch_g, owners, keys_g, slots,
             stale_w=stale_w)
-        new_i = _faults.inject_nonfinite(
-            new_i, fcodes_g == _faults.NONFINITE_GRAD)
-        finite = jax.vmap(_faults.finite_guard)((new_i, new_L))
+        with jax.named_scope("fed.noise"):
+            new_i = _faults.inject_nonfinite(
+                new_i, fcodes_g == _faults.NONFINITE_GRAD)
+            finite = jax.vmap(_faults.finite_guard)((new_i, new_L))
         guard_ok = payload_ok & finite & (fcodes_g != _faults.STALE)
         on_time = deadline_guard(fcodes_g)
         apply = answered & guard_ok & on_time
@@ -1575,78 +1654,35 @@ def make_group_rounds(loss_fn, cfg: AsyncDPConfig,
 
         owners_w = jnp.where(valid, owners, n_owners)          # pad -> drop
         idx_w, idx_c = scatter_indices(bank, owners, valid, slots, hit_g)
-        n_ok = jnp.sum(apply.astype(jnp.float32))
-        denom = jnp.maximum(n_ok, 1.0)
-        hot = bank.hot if slots is not None else bank
-        if isinstance(hot, QuantBank):
-            # same residual chain as the plain body; a NaN-poisoned
-            # member never advances the carry (its `apply` is False by
-            # the finite guard), so poison cannot leak into the shared
-            # residual
-            def _ef_chain(res, inp):
-                v, k, grant = inp
-                c_n, s_n, err = _encode_bank_row(hot, v + res, k,
-                                                 cfg.privatizer)
-                return jnp.where(grant, err, res), (c_n, s_n)
-
-            residual, (codes_n, scales_n) = jax.lax.scan(
-                _ef_chain, hot.residual, (new_i, keys_g, apply))
-            codes_w = jnp.where(_member_mask(apply, codes_n), codes_n,
-                                hot.codes[idx_c])
-            scales_w = jnp.where(apply[:, None], scales_n,
-                                 hot.scales[idx_c])
-            new_hot = QuantBank(
-                hot.codes.at[idx_w].set(codes_w, mode="drop"),
-                hot.scales.at[idx_w].set(scales_w, mode="drop"),
-                residual, hot.codec)
-        else:
-            rows = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(_member_mask(apply, a), a, b),
-                new_i, theta_i)
-            new_hot = _write_bank_rows(hot, rows, idx_w)
-        bank = (bank.replace(hot=new_hot) if slots is not None
-                else new_hot)
-
-        if tr is not None:
-            rows_m = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(_member_mask(apply, a), a, b),
-                new_rows, rows_t)
-            nodes = jax.tree_util.tree_map(
-                lambda leaf, v: leaf.at[idx_w].set(v, mode="drop"),
-                tr.nodes, rows_m)
-            tr = tr.replace(nodes=nodes,
-                            counts=tr.counts.at[owners_w].add(
-                                apply.astype(jnp.int32), mode="drop"))
-
-        def reduce_theta(stacked, base):
-            s = jnp.sum(jnp.where(_member_mask(apply, stacked), stacked,
-                                  jnp.zeros_like(stacked)), axis=0) / denom
-            return jnp.where(n_ok > 0, s.astype(base.dtype), base)
-
-        theta_L = jax.tree_util.tree_map(reduce_theta, new_L, theta_L)
-        if sh is not None:
-            theta_L = _constrain(theta_L, sh.theta)
-            bank = _constrain_bank(bank, sh)
-            tr = _constrain_tree(tr, sh)
-        fs = _faults.update_checksum(fs, bank, owners, apply,
-                                     row_idx=slots)
-        upd = dict(
-            spent=led.spent.at[owners_w].add(
-                answered.astype(jnp.int32), mode="drop"),
-            refused=led.refused.at[owners_w].add(
-                (valid & avail & ~led_auth).astype(jnp.int32), mode="drop"),
-            dropped=led.dropped.at[owners_w].add(
-                (auth & is_drop).astype(jnp.int32), mode="drop"),
-            faulted=led.faulted.at[owners_w].add(
-                guard_rej.astype(jnp.int32), mode="drop"),
-            quarantined=led.quarantined.at[owners_w].add(
-                (valid & quar).astype(jnp.int32), mode="drop"),
-            timed_out=led.timed_out.at[owners_w].add(
-                timed.astype(jnp.int32), mode="drop"))
-        if ss is not None:
-            upd["retried"] = led.retried.at[owners_w].add(
-                is_retry.astype(jnp.int32), mode="drop")
-        ledger = led.replace(**upd)
+        # a NaN-poisoned member never advances the quantized bank's
+        # residual carry: its `apply` is False by the finite guard
+        bank = write_rows(bank, new_i, theta_i, keys_g, apply, idx_w, idx_c,
+                          slots)
+        tr = write_tree(tr, new_rows, rows_t, apply, idx_w, owners_w)
+        theta_L = reduce_theta_L(new_L, theta_L, apply)
+        theta_L, bank, tr = _constrain_state(theta_L, bank, tr, sh)
+        with jax.named_scope("fed.bank_write"):
+            fs = _faults.update_checksum(fs, bank, owners, apply,
+                                         row_idx=slots)
+        with jax.named_scope("fed.ledger"):
+            upd = dict(
+                spent=led.spent.at[owners_w].add(
+                    answered.astype(jnp.int32), mode="drop"),
+                refused=led.refused.at[owners_w].add(
+                    (valid & avail & ~led_auth).astype(jnp.int32),
+                    mode="drop"),
+                dropped=led.dropped.at[owners_w].add(
+                    (auth & is_drop).astype(jnp.int32), mode="drop"),
+                faulted=led.faulted.at[owners_w].add(
+                    guard_rej.astype(jnp.int32), mode="drop"),
+                quarantined=led.quarantined.at[owners_w].add(
+                    (valid & quar).astype(jnp.int32), mode="drop"),
+                timed_out=led.timed_out.at[owners_w].add(
+                    timed.astype(jnp.int32), mode="drop"))
+            if ss is not None:
+                upd["retried"] = led.retried.at[owners_w].add(
+                    is_retry.astype(jnp.int32), mode="drop")
+            ledger = led.replace(**upd)
         # see the fused body: timeouts/retries are not quarantine events
         fs = _faults.fault_tick(fs, owners, guard_rej | (auth & is_drop),
                                 policy, active=valid & avail)

@@ -113,49 +113,62 @@ def private_grad(loss_fn: LossFn, params, batch, key, *,
     if cfg.pre_grouped and cfg.granularity == "microbatch":
         B = cfg.n_microbatches * jax.tree_util.tree_leaves(batch)[0].shape[1]
 
+    # named scopes split the response into the gradient, the clipping
+    # and the noise, for the device profile
     if cfg.granularity == "example":
         def one(ex):
             ex1 = jax.tree_util.tree_map(lambda a: a[None], ex)
-            return jax.grad(lambda p: loss_fn(p, ex1))(params)
+            with jax.named_scope("fed.grad"):
+                return jax.grad(lambda p: loss_fn(p, ex1))(params)
         grads = jax.vmap(one)(batch)                 # leaves (B, ...)
-        norms = jax.vmap(lambda i: _global_norm(
-            jax.tree_util.tree_map(lambda leaf: leaf[i], grads)))(jnp.arange(B))
-        scale = jnp.minimum(1.0, cfg.xi / jnp.maximum(norms, 1e-12))
-        mean_grad = jax.tree_util.tree_map(
-            lambda leaf: jnp.mean(leaf.astype(jnp.float32)
-                               * scale.reshape((-1,) + (1,) * (leaf.ndim - 1)),
-                               axis=0), grads)
-        clip_frac = jnp.mean((norms > cfg.xi).astype(jnp.float32))
-        max_norm = jnp.max(norms)
+        with jax.named_scope("fed.clip"):
+            norms = jax.vmap(lambda i: _global_norm(
+                jax.tree_util.tree_map(lambda leaf: leaf[i], grads)))(
+                    jnp.arange(B))
+            scale = jnp.minimum(1.0, cfg.xi / jnp.maximum(norms, 1e-12))
+            mean_grad = jax.tree_util.tree_map(
+                lambda leaf: jnp.mean(
+                    leaf.astype(jnp.float32)
+                    * scale.reshape((-1,) + (1,) * (leaf.ndim - 1)),
+                    axis=0), grads)
+            clip_frac = jnp.mean((norms > cfg.xi).astype(jnp.float32))
+            max_norm = jnp.max(norms)
     elif cfg.granularity == "microbatch":
         G = cfg.n_microbatches
         assert B % G == 0, (B, G)
 
         def body(carry, mb):
             acc, nclip, mx = carry
-            g = jax.grad(lambda p: loss_fn(p, mb))(params)
-            if cfg.fused_kernel:
-                from repro.kernels.dp_clip_noise.ops import fused_sqnorm_tree
-                norm = jnp.sqrt(fused_sqnorm_tree(
-                    g, block_rows=cfg.kernel_block_rows,
-                    interpret=resolve_interpret(cfg.kernel_interpret)))
-                s = jnp.minimum(1.0, cfg.xi / jnp.maximum(norm, 1e-12))
-                g = jax.tree_util.tree_map(
-                    lambda leaf: (leaf.astype(jnp.float32) * s).astype(leaf.dtype), g)
-            else:
-                g, norm = clip_tree(g, cfg.xi)
-            acc = jax.tree_util.tree_map(
-                lambda a, x: a + x.astype(jnp.float32), acc, g)
-            return (acc, nclip + (norm > cfg.xi), jnp.maximum(mx, norm)), None
+            with jax.named_scope("fed.grad"):
+                g = jax.grad(lambda p: loss_fn(p, mb))(params)
+            with jax.named_scope("fed.clip"):
+                if cfg.fused_kernel:
+                    from repro.kernels.dp_clip_noise.ops import \
+                        fused_sqnorm_tree
+                    norm = jnp.sqrt(fused_sqnorm_tree(
+                        g, block_rows=cfg.kernel_block_rows,
+                        interpret=resolve_interpret(cfg.kernel_interpret)))
+                    s = jnp.minimum(1.0, cfg.xi / jnp.maximum(norm, 1e-12))
+                    g = jax.tree_util.tree_map(
+                        lambda leaf: (leaf.astype(jnp.float32) * s
+                                      ).astype(leaf.dtype), g)
+                else:
+                    g, norm = clip_tree(g, cfg.xi)
+                acc = jax.tree_util.tree_map(
+                    lambda a, x: a + x.astype(jnp.float32), acc, g)
+                return (acc, nclip + (norm > cfg.xi),
+                        jnp.maximum(mx, norm)), None
 
-        zeros = jax.tree_util.tree_map(
-            lambda leaf: jnp.zeros(leaf.shape, jnp.float32), params)
+        with jax.named_scope("fed.clip"):
+            zeros = jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros(leaf.shape, jnp.float32), params)
         xs = batch if cfg.pre_grouped else _group_batch(batch, G)
         (acc, nclip, max_norm), _ = jax.lax.scan(
             body, (zeros, jnp.zeros((), jnp.float32),
                    jnp.zeros((), jnp.float32)), xs)
-        mean_grad = jax.tree_util.tree_map(lambda a: a / G, acc)
-        clip_frac = nclip / G
+        with jax.named_scope("fed.clip"):
+            mean_grad = jax.tree_util.tree_map(lambda a: a / G, acc)
+            clip_frac = nclip / G
     else:
         raise ValueError(cfg.granularity)
 
@@ -167,23 +180,26 @@ def private_grad(loss_fn: LossFn, params, batch, key, *,
         # fuse with the write-out; for 'example' the mean is already taken.
         src, gain = ((acc, 1.0 / G) if cfg.granularity == "microbatch"
                      else (mean_grad, 1.0))
-        noisy = fused_scale_noise_tree(src, key, gain, noise_scale,
-                                       block_rows=cfg.kernel_block_rows,
-                                       interpret=resolve_interpret(
-                                           cfg.kernel_interpret))
+        with jax.named_scope("fed.noise"):
+            noisy = fused_scale_noise_tree(
+                src, key, gain, noise_scale,
+                block_rows=cfg.kernel_block_rows,
+                interpret=resolve_interpret(cfg.kernel_interpret))
         return noisy, {"clip_frac": clip_frac, "max_grad_norm": max_norm}
 
-    if cfg.mechanism == "laplace":
-        noise = laplace_noise_tree(key, mean_grad, noise_scale)
-    elif cfg.mechanism == "gaussian":
-        leaves, treedef = jax.tree_util.tree_flatten(mean_grad)
-        ks = jax.random.split(key, len(leaves))
-        noise = jax.tree_util.tree_unflatten(
-            treedef, [noise_scale * jax.random.normal(k, leaf.shape, jnp.float32)
-                      for k, leaf in zip(ks, leaves)])
-    else:
-        raise ValueError(cfg.mechanism)
-    noisy = jax.tree_util.tree_map(lambda g, w: g + w, mean_grad, noise)
+    with jax.named_scope("fed.noise"):
+        if cfg.mechanism == "laplace":
+            noise = laplace_noise_tree(key, mean_grad, noise_scale)
+        elif cfg.mechanism == "gaussian":
+            leaves, treedef = jax.tree_util.tree_flatten(mean_grad)
+            ks = jax.random.split(key, len(leaves))
+            noise = jax.tree_util.tree_unflatten(
+                treedef, [noise_scale
+                          * jax.random.normal(k, leaf.shape, jnp.float32)
+                          for k, leaf in zip(ks, leaves)])
+        else:
+            raise ValueError(cfg.mechanism)
+        noisy = jax.tree_util.tree_map(lambda g, w: g + w, mean_grad, noise)
     metrics = {"clip_frac": clip_frac, "max_grad_norm": max_norm}
     if return_noise:
         return noisy, metrics, noise

@@ -58,10 +58,17 @@ def as_owner_seq(seq, n_owners: int) -> jax.Array:
         raise ValueError(f"owner sequence must be integer, got {seq.dtype}")
     if isinstance(seq, jax.core.Tracer):
         return seq.astype(jnp.int32)
-    if seq.size and (int(seq.min()) < 0 or int(seq.max()) >= n_owners):
+    if seq.size and (_read_int(seq.min()) < 0
+                     or _read_int(seq.max()) >= n_owners):
         raise ValueError(
             f"owner sequence out of range for {n_owners} owners")
     return seq.astype(jnp.int32)
+
+
+def _read_int(x) -> int:
+    """One blocking device->host read, a `host_sync` span in a trace."""
+    with jax.profiler.TraceAnnotation("host_sync"):
+        return int(x)
 
 
 # ------------------ schedule analysis: conflict-free groups ----------------

@@ -585,7 +585,21 @@ class Federation:
         latency armed, owner_seq=None, and a schedule that exposes
         `draw_with_times`, the times are drawn alongside the owner
         sequence automatically.
+
+        Under `jax.profiler` the call writes host spans into the trace:
+        `run_rounds` around the whole call, one `host_sync` around each
+        blocking device->host read (a caller-given sequence's bounds
+        check reads its min and max; the shared host copy is one more),
+        and `launch` around the call into the jitted driver until it
+        returns. With the profiler off they cost about a microsecond.
         """
+        with jax.profiler.TraceAnnotation("run_rounds"):
+            return self._run_rounds(state, batches, owner_seq, key, faults,
+                                    latency, times, owner_parallel,
+                                    max_group)
+
+    def _run_rounds(self, state, batches, owner_seq, key, faults, latency,
+                    times, owner_parallel, max_group):
         if self.strategy != "async":
             raise ValueError("run_rounds() is the async path")
         if key is None:
@@ -604,7 +618,8 @@ class Federation:
         def host_seq() -> np.ndarray:
             nonlocal seq_host
             if seq_host is None:
-                seq_host = np.asarray(owner_seq)
+                with jax.profiler.TraceAnnotation("host_sync"):
+                    seq_host = np.asarray(owner_seq)
             return seq_host
 
         if isinstance(owner_seq, TraceRing):
@@ -682,11 +697,11 @@ class Federation:
         # same key as FaultPlan.draw by contract: draw folds in
         # FAULT_SALT, so the fault stream never touches the round keys
         keys = jax.random.split(key, k_rounds)  # dpcheck: ignore[DPC105]
+        codes = () if fault_codes is None else (fault_codes,)
         if not owner_parallel:
-            if fault_codes is None:
-                return self._fused_fn(state, batches, owner_seq, keys)
-            return self._fused_fn(state, batches, owner_seq, keys,
-                                  fault_codes)
+            with jax.profiler.TraceAnnotation("launch"):
+                out = self._fused_fn(state, batches, owner_seq, keys, *codes)
+            return out
 
         # schedule analysis is a host-side pass over the shared host
         # copy: at most one device->host sync per dispatch, not one per
@@ -698,10 +713,9 @@ class Federation:
         if all(length <= 1 for _, length in groups):
             # every group is a single round: the sequential scan IS the
             # grouped execution, bit-for-bit
-            if fault_codes is None:
-                return self._fused_fn(state, batches, owner_seq, keys)
-            return self._fused_fn(state, batches, owner_seq, keys,
-                                  fault_codes)
+            with jax.profiler.TraceAnnotation("launch"):
+                out = self._fused_fn(state, batches, owner_seq, keys, *codes)
+            return out
         idx, valid = pack_groups(groups)
         # Shape-stabilize for the jit cache: schedule-drawn partitions
         # give a different (n_groups, G_max) almost every dispatch, and
@@ -718,14 +732,10 @@ class Federation:
         rows = -(-n_g // 4) * 4
         idx = np.pad(idx, ((0, rows - n_g), (0, gpad - gmax)))
         valid = np.pad(valid, ((0, rows - n_g), (0, gpad - gmax)))
-        if fault_codes is None:
+        with jax.profiler.TraceAnnotation("launch"):
             state, gm = self._group_fn(state, batches, owner_seq, keys,
                                        jnp.asarray(idx), jnp.asarray(valid),
-                                       jnp.int32(n_g))
-        else:
-            state, gm = self._group_fn(state, batches, owner_seq, keys,
-                                       jnp.asarray(idx), jnp.asarray(valid),
-                                       jnp.int32(n_g), fault_codes)
+                                       jnp.int32(n_g), *codes)
         # group-major (n_groups, G_max) -> round-order (K,): groups are
         # consecutive and in order, so the valid entries flatten in order
         order = np.flatnonzero(valid.reshape(-1))

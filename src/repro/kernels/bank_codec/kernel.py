@@ -72,6 +72,7 @@ def absmax_2d(x: jax.Array, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct(
             (nb * PARTIAL_TILE[0], PARTIAL_TILE[1]), jnp.float32),
         interpret=interpret,
+        name="bank_absmax",
     )(x)
     return jnp.max(partial)
 
@@ -105,6 +106,7 @@ def encode_2d(x: jax.Array, bits: jax.Array, scale: jax.Array, fmt: str, *,
         out_shape=[jax.ShapeDtypeStruct((R, C), code_dtype),
                    jax.ShapeDtypeStruct((R, C), jnp.float32)],
         interpret=interpret,
+        name="bank_encode",
     )(x, bits, scale)
 
 
@@ -123,6 +125,7 @@ def decode_2d(codes: jax.Array, scale: jax.Array, fmt: str, *,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
         interpret=interpret,
+        name="bank_decode",
     )(codes, scale)
 
 

@@ -100,6 +100,7 @@ def scale_noise_2d(g: jax.Array, bits: jax.Array, clip_scale: jax.Array,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), g.dtype),
         interpret=interpret,
+        name="scale_noise",
     )(g, bits, clip_scale, noise_scale)
 
 
@@ -131,6 +132,7 @@ def dp_round_2d(tb: jax.Array, acc: jax.Array, bits: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
                    jax.ShapeDtypeStruct((R, C), jnp.float32)],
         interpret=interpret,
+        name="dp_round",
     )(tb, acc, bits, gain, noise_scale, w)
 
 
@@ -148,5 +150,10 @@ def sqnorm_2d(g: jax.Array, *, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((nb * PARTIAL_TILE[0], PARTIAL_TILE[1]),
                                        jnp.float32),
         interpret=interpret,
+        # the compiled call is named after the kernel, and the partial-sum
+        # fusion below names it as its operand: a profile's name match on
+        # "sqnorm" (bench/work/stages/clip_norm.py) would take that fusion
+        # too, so this name leaves it out
+        name="squared_norm",
     )(g)
     return jnp.sum(partial[::PARTIAL_TILE[0], 0])

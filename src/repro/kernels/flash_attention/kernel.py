@@ -116,4 +116,5 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

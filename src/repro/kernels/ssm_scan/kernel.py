@@ -73,5 +73,6 @@ def ssd_chunk_scan(v: jax.Array, k: jax.Array, q: jax.Array, ld: jax.Array,
             jax.ShapeDtypeStruct((B, H, nc, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_chunk_scan",
     )(v, k, q, ld, g)
     return y, hadd, cum, tot

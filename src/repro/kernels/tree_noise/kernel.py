@@ -64,4 +64,5 @@ def tree_delta_2d(nodes, bits, count, noise_scale, *, block_rows: int = 64,
         out_shape=[jax.ShapeDtypeStruct((rows, cols), jnp.float32),
                    jax.ShapeDtypeStruct((depth, rows, cols), jnp.float32)],
         interpret=interpret,
+        name="tree_delta",
     )(nodes, bits, count, noise_scale)

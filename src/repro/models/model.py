@@ -327,10 +327,14 @@ class LM:
     def _xlstm_stack(self, blocks, x):
         cfg = self.cfg
         for i, blk in enumerate(blocks):
+            # the scopes name each block kind's operations (forward and,
+            # under jax.grad, transpose(jvp(...))) in the compiled HLO
             if "slstm" in blk:
-                x = x + xlstm_mod.slstm_forward(blk["slstm"], x, cfg)
+                with jax.named_scope("model.slstm"):
+                    x = x + xlstm_mod.slstm_forward(blk["slstm"], x, cfg)
             else:
-                x = x + xlstm_mod.mlstm_forward(blk["mlstm"], x, cfg)
+                with jax.named_scope("model.mlstm"):
+                    x = x + xlstm_mod.mlstm_forward(blk["mlstm"], x, cfg)
         return x
 
     # ---------------- loss -------------------------------------------

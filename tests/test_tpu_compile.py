@@ -102,6 +102,14 @@ def _cases(p, one_chip):
 KERNELS = ["dp_round_2d", "scale_noise_2d", "sqnorm_2d", "absmax_2d",
            "encode_2d_int8", "encode_2d_fp8", "decode_2d_int8",
            "decode_2d_fp8", "tree_delta_2d"]
+# The `name=` of each case's Pallas call: the compiled custom call carries
+# it in its op_name, where profiles and the benchmark's stages find it.
+KERNEL_NAMES = {"dp_round_2d": "dp_round", "scale_noise_2d": "scale_noise",
+                "sqnorm_2d": "squared_norm", "absmax_2d": "bank_absmax",
+                "encode_2d_int8": "bank_encode",
+                "encode_2d_fp8": "bank_encode",
+                "decode_2d_int8": "bank_decode",
+                "decode_2d_fp8": "bank_decode", "tree_delta_2d": "tree_delta"}
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -112,10 +120,15 @@ def test_kernel_compiles_for_v5e_at_xlstm_width(name, topo,
     one_chip = SingleDeviceSharding(topo.devices[0])
     fn, args = _cases(xlstm_p, one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert f'/{KERNEL_NAMES[name]}/pallas_call"' in line, line[:300]
 
 
 def test_cases_cover_every_listed_kernel(xlstm_p):
     # the parametrization and the case table must not drift apart
     assert sorted(_cases(xlstm_p, None)) == sorted(KERNELS)
+    assert sorted(KERNEL_NAMES) == sorted(KERNELS)
     assert xlstm_p > 100_000_000        # published widths, not reduced()
