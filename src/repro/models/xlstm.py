@@ -174,12 +174,8 @@ def init_slstm(key, cfg: ModelConfig, dtype) -> SLSTMParams:
     )
 
 
-def _slstm_cell(p: SLSTMParams, zin: jax.Array, st: SLSTMState) -> Tuple[SLSTMState, jax.Array]:
-    """zin: (B,H,hd,4) pre-activations from input; recurrent added here."""
-    rec = jnp.einsum("bhd,hdkg->bhkg", st.hst.astype(jnp.float32),
-                     p.r.astype(jnp.float32))
-    pre = zin.astype(jnp.float32) + rec + p.b
-    i_raw, f_raw, z_raw, o_raw = [pre[..., j] for j in range(4)]
+def _slstm_gates(i_raw, f_raw, z_raw, o_raw, st: SLSTMState) -> SLSTMState:
+    """The cell's pointwise part, from its four gate pre-activations (f32)."""
     log_f = -jax.nn.softplus(-f_raw)             # log sigmoid — stabilized f
     m_new = jnp.maximum(log_f + st.m, i_raw)
     i_t = jnp.exp(i_raw - m_new)
@@ -189,20 +185,83 @@ def _slstm_cell(p: SLSTMParams, zin: jax.Array, st: SLSTMState) -> Tuple[SLSTMSt
     c_new = f_t * st.c + i_t * z_t
     n_new = f_t * st.n + i_t
     h_new = o_t * c_new / jnp.maximum(n_new, 1e-6)
-    return SLSTMState(c_new, n_new, h_new, m_new), h_new
+    return SLSTMState(c_new, n_new, h_new, m_new)
+
+
+def _slstm_cell(p: SLSTMParams, zin: jax.Array, st: SLSTMState) -> Tuple[SLSTMState, jax.Array]:
+    """zin: (B,H,hd,4) pre-activations from input; recurrent added here."""
+    rec = jnp.einsum("bhd,hdkg->bhkg", st.hst.astype(jnp.float32),
+                     p.r.astype(jnp.float32))
+    pre = zin.astype(jnp.float32) + rec + p.b
+    st2 = _slstm_gates(*[pre[..., j] for j in range(4)], st)
+    return st2, st2.hst
+
+
+# The time scan below holds every gate-indexed array gate-major, the
+# (hd, 4) pair flattened to 4*hd with hd minor: a trailing gate axis of 4
+# would be padded to a whole lane tile on TPU. It has a hand-written
+# backward so that no parameter cotangent rides in the loop carry: the
+# reverse scan carries only the state cotangents and emits each step's
+# pre-activation cotangent; dr and db are contracted once, after it.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _slstm_run(r, b, zin, save: bool):
+    """r (H, hd, 4*hd), b (H, 4*hd), zin (S, B, H, 4*hd), all f32.
+    Returns hs (S, B, H, hd) f32 and, if `save`, the backward's residuals:
+    each step's pre-activations and its incoming state."""
+    _, B, H, _ = zin.shape
+    z = jnp.zeros((B, H, r.shape[1]), jnp.float32)
+    st0 = SLSTMState(z, z, z, jnp.full_like(z, -1e30))
+
+    def step(st, z_t):
+        rec = jnp.einsum("bhd,hdn->bhn", st.hst, r, precision=_HIGHEST)
+        pre = z_t + rec + b
+        st2 = _slstm_gates(*jnp.split(pre, 4, axis=-1), st)
+        return st2, ((st2.hst, pre, st) if save else st2.hst)
+
+    return jax.lax.scan(step, st0, zin)[1]
+
+
+@jax.custom_vjp
+def _slstm_scan(r, b, zin):
+    return _slstm_run(r, b, zin, save=False)
+
+
+def _slstm_scan_fwd(r, b, zin):
+    hs, pre, prev = _slstm_run(r, b, zin, save=True)
+    return hs, (r, pre, prev)
+
+
+def _slstm_scan_bwd(res, dhs):
+    r, pre, prev = res
+
+    def step(dst, xs):
+        dh_t, pre_t, st = xs
+        _, vjp = jax.vjp(
+            lambda p, s: _slstm_gates(*jnp.split(p, 4, axis=-1), s), pre_t, st)
+        dpre, dprev = vjp(dst._replace(hst=dst.hst + dh_t))
+        dh = jnp.einsum("bhn,hdn->bhd", dpre, r, precision=_HIGHEST)
+        return dprev._replace(hst=dh), dpre
+
+    z = jnp.zeros(dhs.shape[1:], jnp.float32)
+    _, dpre = jax.lax.scan(step, SLSTMState(z, z, z, z), (dhs, pre, prev),
+                           reverse=True)
+    dr = jnp.einsum("sbhd,sbhn->hdn", prev.hst, dpre, precision=_HIGHEST)
+    return dr, dpre.sum((0, 1)), dpre
+
+
+_slstm_scan.defvjp(_slstm_scan_fwd, _slstm_scan_bwd)
 
 
 def slstm_forward(p: SLSTMParams, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     B, S, d = x.shape
     H, hd, fs = slstm_dims(cfg)
-    zin = jnp.einsum("bsd,dhkg->bshkg", x, p.w_in)
-
-    def step(st, z_t):
-        st2, h = _slstm_cell(p, z_t, st)
-        return st2, h
-
-    st0 = init_slstm_state(B, cfg)
-    _, hs = jax.lax.scan(step, st0, zin.swapaxes(0, 1))
+    # gate-major layouts, built once per call
+    zin = jnp.einsum("bsd,dhkg->sbhgk", x, p.w_in).reshape(S, B, H, 4 * hd)
+    r = p.r.transpose(0, 1, 3, 2).reshape(H, hd, 4 * hd)
+    b = p.b.swapaxes(1, 2).reshape(H, 4 * hd)
+    hs = _slstm_scan(*(a.astype(jnp.float32) for a in (r, b, zin)))
     y = hs.swapaxes(0, 1).reshape(B, S, d).astype(x.dtype)
     y = rms_norm(y, p.norm)
     up = jnp.einsum("bsd,df->bsf", y, p.w_up)

@@ -1,5 +1,6 @@
-"""Compile rehearsals: every Pallas kernel of the main path, compiled for
-one chip of a described TPU v5e at the full width of xlstm-125m.
+"""Compile rehearsals: every Pallas kernel of the main path, and the sLSTM
+block's gradient, compiled for one chip of a described TPU v5e at the
+full width of xlstm-125m.
 
 Nothing runs: the TPU compiler is asked to accept each kernel at the
 (rows, 1024) layout the flat engine hands it, and the compiled program
@@ -11,6 +12,8 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -132,3 +135,58 @@ def test_cases_cover_every_listed_kernel(xlstm_p):
     assert sorted(_cases(xlstm_p, None)) == sorted(KERNELS)
     assert sorted(KERNEL_NAMES) == sorted(KERNELS)
     assert xlstm_p > 100_000_000        # published widths, not reduced()
+
+
+def _loop_text(hlo):
+    """The carry tuples of every `while` in `hlo`, and the text of every
+    computation a while body or condition reaches through its calls."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    carries, todo = [], []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*%[\w.\-]+ = (\(.*\)) while\(", line)
+        if m:
+            carries.append(m.group(1))
+            todo += re.findall(r"(?:body|condition)=%([\w.\-]+)", line)
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [n for line in comps[c]
+                     for n in re.findall(r"%([\w.\-]+)", line) if n in comps]
+    return carries, "\n".join(line for c in seen for line in comps[c])
+
+
+def test_slstm_grad_keeps_r_out_of_its_loops(topo, no_persistent_cache):
+    """The sLSTM scans carry no (H, hd, hd, 4) f32 buffer: with the gate
+    axis minor it is padded to a lane tile each, 32 x its data, and each
+    step of a backward that accumulates dr in its carry moves it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.configs import get_config
+    from repro.models import xlstm
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("xlstm-125m")
+    H, hd, _ = xlstm.slstm_dims(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: xlstm.init_slstm(k, cfg, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 1024, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = jax.grad(lambda p, x: jnp.sum(
+        xlstm.slstm_forward(p, x, cfg).astype(jnp.float32)), (0, 1))
+    hlo = jax.jit(grad).lower(params, x).compile().as_text()
+    carries, loops = _loop_text(hlo)
+    assert len(carries) == 2            # the forward and the backward scan
+    padded = f"f32[{H},{hd},{hd},4]"
+    assert padded == "f32[4,192,192,4]"
+    assert not any(padded in c for c in carries)
+    assert padded not in loops
