@@ -126,7 +126,9 @@ class CellRun:
         self.cell, self.seed, self.spans = cell, seed, spans
         t = cell.traffic
         mcfg = model_config(cell.model)
-        model = build_model(mcfg, remat=False)
+        # checkpointed, as a deployment trains; it changes no math, and
+        # an xLSTM stack has nothing to checkpoint
+        model = build_model(mcfg, remat=True)
         self.shapes = jax.eval_shape(
             lambda: model.init(jax.random.PRNGKey(0), jnp.float32))
         self.weights = weight_fn(self.shapes)
@@ -225,12 +227,10 @@ def reference_changes(cell: Cell, shapes, weights, weight_key, seq, batches,
     ref = Reference(cell.model, loss, shapes, rnd,
                     dtype=dtype or jnp.float32, half_batch=half_batch)
     L = ref.layout
-    theta0 = jax.jit(lambda k: L.flat(weights(k)))(weight_key)
     first = seq[:CHECK_FIRST]
-    out = ref.follow(theta0, first,
+    out = ref.follow(jax.jit(lambda k: L.flat(weights(k)))(weight_key), first,
                      {k: v[:CHECK_FIRST] for k, v in batches.items()}, key,
                      rounds=len(seq))
-    del theta0
     row_norms = jax.jit(lambda r, r0: L.leaf_norms(
         r.astype(jnp.float32) - r0.astype(jnp.float32)))
     d_rows = np.stack([np.asarray(row_norms(out["rows"][int(o)],
@@ -309,6 +309,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     compiles_before = compiles.n
     attempted = granted = refused = 0
     n = 0
+    ends = []             # the window's clock as each dispatch returns
     start = time.perf_counter()
     while True:
         m = r.dispatch()
@@ -317,6 +318,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         granted += int((~m["refused"]).sum())
         n += 1
         elapsed = time.perf_counter() - start
+        ends.append(elapsed)
         if elapsed >= seconds or (trace and n >= TRACE_DISPATCHES):
             break
     r.wait()
@@ -326,6 +328,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         jax.profiler.stop_trace()
         spans.tracing = False
     peak = memory_peak(devices)
+    print(f"window: seconds of each dispatch {np.diff([0.0] + ends).tolist()}",
+          file=sys.stderr)
 
     # the ledger, reconciled, against the reference's count
     ledger = r.fed.reconcile(r.state)

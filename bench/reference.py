@@ -8,9 +8,10 @@ gives what the program's state and round metrics should hold:
   (per-record clipping: one sequence per microbatch), from the
   configuration's own plain loss (`configs/<config>.py`, `lm_loss`);
 - the Laplace draw of Theorem 1 (scale 2 * clip * T / (n_i * eps_i)):
-  threefry bits of the round key over the flat parameter vector padded
-  to whole (256 x 1024) blocks, the top 24 bits as a uniform, and the
-  inverse CDF;
+  threefry bits of the round key over the flat parameter vector, which
+  under JAX's partitionable threefry are the first P bits of the padded
+  (rows, 1024) blocks the program draws over, the top 24 bits as a
+  uniform, and the inverse CDF;
 - the inertia update of eqs. 5-7 with the paper's rates, the theta_max
   projection, and the owner's row written to a bf16 bank.
 
@@ -27,9 +28,6 @@ from typing import Any, Callable, Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-LANES = 1024
-BLOCK_ROWS = 256          # bits are drawn over whole (256, 1024) blocks
 
 
 # ------------------------------------------------------- flat parameters
@@ -77,18 +75,22 @@ class Layout:
             out.append(jnp.sqrt(jnp.sum(jnp.square(part))))
         return jnp.stack(out)
 
-    @property
-    def padded(self) -> int:
-        per = BLOCK_ROWS * LANES
-        return -(-self.size // per) * per
-
 
 def laplace(key, layout: Layout, dtype):
     """Unit Laplace draws over the flat vector: threefry bits of `key`
-    over the padded vector, top 24 bits -> u in [0, 1), inverse CDF. The
-    grid's end point u = 0 is clamped to the nearest interior value."""
-    bits = jax.random.bits(key, (layout.padded // LANES, LANES),
-                           jnp.uint32).reshape(-1)[:layout.size]
+    over (P,), top 24 bits -> u in [0, 1), inverse CDF. The grid's end
+    point u = 0 is clamped to the nearest interior value.
+
+    Under JAX's partitionable threefry (its default) an element's bits
+    depend only on its flat index, so these are the first P bits of the
+    padded (rows, 1024) blocks. XLA:TPU fuses the draw over (P,) into its
+    consumers; a (rows, 1024) draw materialises its counters, 10.0 GB of
+    temporaries at P = 501,156,000."""
+    if not jax.config.jax_threefry_partitionable:
+        raise ValueError("the reference's noise needs "
+                         "jax_threefry_partitionable: without it the bits "
+                         "over (P,) are not those of the padded blocks")
+    bits = jax.random.bits(key, (layout.size,), jnp.uint32)
     u = (bits >> 8).astype(jnp.int32).astype(jnp.float32) * 2.0 ** -24
     v = jnp.clip(u - 0.5, -0.4999999, 0.4999999)
     return (-jnp.sign(v) * jnp.log1p(-2.0 * jnp.abs(v))).astype(dtype)
@@ -138,19 +140,23 @@ class Reference:
 
         def round_grads(tb, tokens, labels):
             """Clipped gradient sum over a round's records (B, S), and the
-            largest per-record norm; the records run side by side."""
+            largest per-record norm. The records run one at a time, so
+            one record's gradient and activations are live at once, not
+            B of them."""
             params = L.unflat(tb.astype(dt))
 
-            def one(t, lab):
-                return L.flat(jax.grad(loss)(params, t, lab, model)
-                              ).astype(dt)
+            def one(carry, record):
+                acc, top = carry
+                t, lab = record
+                g = L.flat(jax.grad(loss)(params, t, lab, model)).astype(dt)
+                norm = jnp.sqrt(jnp.sum(g * g))
+                s = jnp.minimum(1.0, rnd.clip / jnp.maximum(norm, 1e-12))
+                acc = acc + (g * s.astype(dt)).astype(jnp.float32)
+                return (acc, jnp.maximum(top, norm)), None
 
-            g = jax.vmap(one)(tokens, labels)                      # (B, P)
-            norms = jnp.sqrt(jnp.sum(g * g, axis=1))
-            s = jnp.minimum(1.0, rnd.clip / jnp.maximum(norms, 1e-12))
-            acc = jnp.sum((g * s[:, None].astype(dt)).astype(jnp.float32),
-                          axis=0)
-            return acc, jnp.max(norms)
+            init = (jnp.zeros(L.size, jnp.float32), jnp.zeros((), dt))
+            (acc, top), _ = jax.lax.scan(one, init, (tokens, labels))
+            return acc, top
 
         def update(tb, acc, key, gain, b, w):
             tb = tb.astype(dt)
@@ -180,10 +186,13 @@ class Reference:
         round keys are split from `key`) from weights `theta0` (flat f32):
         one round per entry of `owner_seq`. Returns the largest
         per-record gradient norm of each round, the learner's model and
-        the bank rows the rounds wrote."""
+        the bank rows the rounds wrote. Where the caller keeps no other
+        reference to `theta0`, it is freed once the first round has read
+        it."""
         rnd = self.rnd
         theta_L = theta0
         row0 = theta0.astype(jnp.bfloat16)
+        del theta0
         rows: Dict[int, Any] = {}
         keys = jax.random.split(jnp.asarray(key, jnp.uint32), rounds)
         B = batches["tokens"].shape[1]

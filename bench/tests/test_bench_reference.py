@@ -1,6 +1,7 @@
-"""The plain reference's parts on their own: the records of a round run
-side by side give what one record at a time gives, the Laplace draws are
-unit Laplace, and the ledger serves an owner up to its horizon."""
+"""The plain reference's parts on their own: its compiled round, which
+scans a round's records, gives what a loop over them in Python gives,
+the Laplace draws are unit Laplace, and the ledger serves an owner up to
+its horizon."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,7 +53,6 @@ def test_batched_records_match_one_at_a_time():
 def test_laplace_draws_are_unit_laplace():
     L = reference.Layout.of({"w": jax.ShapeDtypeStruct((300_000,),
                                                        jnp.float32)})
-    assert L.padded == 2 * 256 * 1024
     key = jnp.asarray([1, 2], jnp.uint32)
     x = np.asarray(reference.laplace(key, L, jnp.float32))
     assert x.shape == (300_000,) and np.all(np.isfinite(x))
@@ -60,6 +60,33 @@ def test_laplace_draws_are_unit_laplace():
         x, np.asarray(reference.laplace(key, L, jnp.float32)))
     assert np.mean(np.abs(x)) == pytest.approx(1.0, abs=0.01)   # E|X| = 1
     assert np.mean(x) == pytest.approx(0.0, abs=0.01)
+
+
+def test_laplace_draws_are_the_padded_blocks_bits():
+    # the program draws its noise over whole (256, 1024) blocks
+    L = reference.Layout.of({"w": jax.ShapeDtypeStruct((300_001,),
+                                                       jnp.float32)})
+    rows = -(-L.size // (256 * 1024)) * 256
+    key = jnp.asarray([7, 2**31 + 5], jnp.uint32)
+    bits = np.asarray(jax.random.bits(key, (rows, 1024), jnp.uint32)
+                      ).reshape(-1)[:L.size]
+    np.testing.assert_array_equal(
+        bits, np.asarray(jax.random.bits(key, (L.size,), jnp.uint32)))
+    u = bits >> 8
+    v = np.clip(u.astype(np.float32) * np.float32(2.0 ** -24) - 0.5,
+                -0.4999999, 0.4999999)
+    want = -np.sign(v) * np.log1p(-2.0 * np.abs(v))
+    got = np.asarray(reference.laplace(key, L, jnp.float32))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+
+
+def test_laplace_refuses_the_legacy_threefry():
+    L = reference.Layout.of({"w": jax.ShapeDtypeStruct((1000,),
+                                                       jnp.float32)})
+    with jax.threefry_partitionable(False):
+        with pytest.raises(ValueError, match="partitionable"):
+            reference.laplace(jnp.asarray([1, 2], jnp.uint32), L,
+                              jnp.float32)
 
 
 def test_ledger_serves_up_to_the_horizon():
